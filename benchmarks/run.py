@@ -49,10 +49,13 @@ def decode_headlines() -> list:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated module substrings")
     args = ap.parse_args()
+    use_compile_cache()
     selected = MODULES
     if args.only:
         keys = args.only.split(",")

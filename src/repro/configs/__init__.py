@@ -1,6 +1,6 @@
 """Config registry: --arch <id> resolution."""
-from repro.configs.base import (ArchConfig, ShapeConfig, SHAPES,
-                                SMOKE_SHAPES)
+from repro.configs.base import (ArchConfig, ShapeConfig, DIT_SHAPES,
+                                DIT_TRAIN_SHAPES, SHAPES, SMOKE_SHAPES)
 
 _ARCH_MODULES = {
     "h2o-danube-3-4b": "h2o_danube_3_4b",
@@ -28,7 +28,14 @@ def get_arch(name: str) -> ArchConfig:
 
 
 def get_shape(name: str, smoke: bool = False) -> ShapeConfig:
-    return (SMOKE_SHAPES if smoke else SHAPES)[name]
+    """Shape by name: the assigned SHAPES (or their SMOKE_SHAPES
+    reductions), else a DiT shape by its ShapeConfig name."""
+    if smoke:
+        return SMOKE_SHAPES[name]
+    if name in SHAPES:
+        return SHAPES[name]
+    return {s.name: s for s in (*DIT_SHAPES.values(),
+                                *DIT_TRAIN_SHAPES)}[name]
 
 
 __all__ = ["ArchConfig", "ShapeConfig", "SHAPES", "SMOKE_SHAPES",

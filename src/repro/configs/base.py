@@ -112,6 +112,10 @@ DIT_SHAPES = {
     "wan2_1_1_3b": ShapeConfig("dit_video_32k", 32768, 16, "train"),
     "lightningdit_1b": ShapeConfig("dit_image_1k", 1024, 256, "train"),
 }
+# Reduced DiT train shapes. dit_video_4k: wan2_1_1_3b fine-tuning on one
+# four-chip v5e host, one sample per chip at an eighth of the video
+# tokens; the distillation teacher's dense (N, N) scores bound N.
+DIT_TRAIN_SHAPES = (ShapeConfig("dit_video_4k", 4096, 4, "train"),)
 
 # Reduced shapes for CPU smoke tests (same kinds).
 SMOKE_SHAPES = {

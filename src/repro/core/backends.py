@@ -8,8 +8,9 @@ plan. Three built-in backends, all returning (O^s, O^l):
              validation only)
   gather     LUT-gather XLA path whose compiled FLOPs equal the true
              sparse cost (training / dry-run / any-backend production)
-  kernel     fused Pallas TPU kernels with custom_vjp (interpret mode
-             on CPU)
+  kernel     fused Pallas TPU kernels with custom_vjp (compiled on the
+             TPU; interpret mode where the default backend is the CPU,
+             `repro.kernels.mode.default_interpret`)
 
 `execute(plan, params, q, k, v, cfg, backend=...)` is the single entry
 point every model goes through — it owns mode dispatch ("sla" /
@@ -19,7 +20,6 @@ backends register with `@register_backend("name")`.
 """
 from __future__ import annotations
 
-import warnings
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -91,10 +91,7 @@ def _gather_backend(plan, q, k, v, qp, kp, cfg, scale):
 @register_backend("kernel")
 def _kernel_backend(plan, q, k, v, qp, kp, cfg, scale):
     from repro.kernels import ops as kops
-    # interpret=True on CPU hosts; on a real TPU the kernel is compiled.
-    interpret = jax.default_backend() != "tpu"
-    return kops.sla_attention_core(q, k, v, qp, kp, plan, cfg,
-                                   scale=scale, interpret=interpret)
+    return kops.sla_attention_core(q, k, v, qp, kp, plan, cfg, scale=scale)
 
 
 def _repeat_kv(x: jax.Array, num_q_heads: int) -> jax.Array:
@@ -199,10 +196,6 @@ _DECODE_BACKENDS: Dict[str, BackendFn] = {}
 # "kernel" is the real fused Pallas decode kernel (kernels/sla_decode);
 # "xla" names the un-fused gather/einsum chain explicitly.
 _DECODE_ALIASES = {"pallas": "kernel", "xla": "gather", "dense": "reference"}
-
-# one-line warning (once per process) when the Pallas decode kernel has
-# no TPU and falls back to interpret mode
-_warned_interpret_decode = False
 
 
 def register_decode_backend(name: str) -> Callable[[BackendFn], BackendFn]:
@@ -347,20 +340,12 @@ def _decode_gather_backend(state, qg, qpg, pos, cfg, scale):
 def _decode_kernel_backend(state, qg, qpg, pos, cfg, scale):
     """Fused Pallas decode kernel (kernels/sla_decode): one launch for
     sparse softmax over the LUT pages + the subtractive marginal linear
-    branch. Interpret-mode fallback keeps CPU CI honest (identical
-    numerics, no Mosaic lowering)."""
+    branch. Interpreted only where the default backend is the CPU
+    (identical numerics, no Mosaic lowering)."""
     from repro.kernels import sla_decode
 
-    interpret = jax.default_backend() != "tpu"
-    if interpret:
-        global _warned_interpret_decode
-        if not _warned_interpret_decode:
-            _warned_interpret_decode = True
-            warnings.warn("SLA decode kernel: no TPU backend — running "
-                          "Pallas in interpret mode", stacklevel=2)
     o_s, o_l = sla_decode.decode_attention(
-        state, qg[..., None, :], qpg[..., None, :], pos, cfg, scale,
-        interpret=interpret)
+        state, qg[..., None, :], qpg[..., None, :], pos, cfg, scale)
     return o_s[..., 0, :], o_l[..., 0, :]
 
 
@@ -493,12 +478,4 @@ def decode_execute_chunk(
 def _decode_kernel_backend_chunk(state, qg, qpg, pos, cfg, scale):
     from repro.kernels import sla_decode
 
-    interpret = jax.default_backend() != "tpu"
-    if interpret:
-        global _warned_interpret_decode
-        if not _warned_interpret_decode:
-            _warned_interpret_decode = True
-            warnings.warn("SLA decode kernel: no TPU backend — running "
-                          "Pallas in interpret mode", stacklevel=2)
-    return sla_decode.decode_attention(state, qg, qpg, pos, cfg, scale,
-                                       interpret=interpret)
+    return sla_decode.decode_attention(state, qg, qpg, pos, cfg, scale)

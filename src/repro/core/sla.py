@@ -23,7 +23,7 @@ Modes (cfg.mode):
 
 `backend` selects the execution path from the core.backends registry:
 "reference" (dense oracle), "gather" (LUT-gather XLA — true sparse
-compiled FLOPs), or "kernel" (fused Pallas; interpret mode off-TPU).
+compiled FLOPs), or "kernel" (fused Pallas; interpreted on the CPU).
 """
 from __future__ import annotations
 

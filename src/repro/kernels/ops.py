@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.core.config import SLAConfig
 from repro.core.plan import SLAPlan, plan_from_mask
+from repro.kernels.mode import default_interpret
 from repro.kernels.sla_fwd import sla_fwd
 from repro.kernels.sla_bwd import sla_bwd_dq, sla_bwd_dkv
 
@@ -167,8 +168,8 @@ def sla_attention_rows(
     q: jax.Array, k: jax.Array, v: jax.Array,
     qp: jax.Array, kp: jax.Array,
     marginal: jax.Array, lut: jax.Array, counts: jax.Array,
-    cfg: SLAConfig, scale: float | None = None, interpret: bool = True,
-    row_offset=0,
+    cfg: SLAConfig, scale: float | None = None,
+    interpret: bool | None = None, row_offset=0,
 ) -> Tuple[jax.Array, jax.Array]:
     """Forward-only fused kernel over a SPAN of query-row blocks.
 
@@ -182,8 +183,11 @@ def sla_attention_rows(
     per-block h/z einsums run at full bucket width and the row
     reductions are batch-independent, so chunk outputs are bitwise
     equal to the same rows of the blocking forward. No custom_vjp:
-    prefill chunks are inference-only.
+    prefill chunks are inference-only. `interpret` None decides by the
+    default backend (`repro.kernels.mode.default_interpret`).
     """
+    if interpret is None:
+        interpret = default_interpret()
     scale = float(q.shape[-1] ** -0.5) if scale is None else float(scale)
     fq, fk, fv, fqp, fkp = map(_flat, (q, k, v, qp, kp))
     a, flut, fcounts = map(_flat, (marginal, lut, counts))
@@ -201,12 +205,16 @@ def sla_attention_core(
     q: jax.Array, k: jax.Array, v: jax.Array,
     qp: jax.Array, kp: jax.Array,
     plan: Union[SLAPlan, jax.Array], cfg: SLAConfig,
-    scale: float | None = None, interpret: bool = True,
+    scale: float | None = None, interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused-kernel SLA core. All of q,k,v,qp,kp are (B, H, N, D); `plan`
     is an SLAPlan (or, for convenience, a raw (B, H, Tm, Tn) int8 M_c,
     from which a plan is derived). Returns (O^s, O^l) f32, (B, H, N, D).
+    `interpret` None decides by the default backend
+    (`repro.kernels.mode.default_interpret`).
     """
+    if interpret is None:
+        interpret = default_interpret()
     if not isinstance(plan, SLAPlan):
         plan = plan_from_mask(plan, cfg)
     scale = float(q.shape[-1] ** -0.5) if scale is None else float(scale)

@@ -24,6 +24,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import default_interpret
+from repro.kernels.sla_fwd import head_runs
+
 NEG_INF = -1e30
 
 
@@ -32,9 +35,10 @@ def _dot(a, b, trans_a=False, trans_b=False):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
-def _recompute_p(q, kk, lse_row, *, scale, causal, i, j, block_q, block_kv):
+def _recompute_p(q, kk, lse_col, *, scale, causal, i, j, block_q, block_kv):
     """P_ij = exp(S_ij - L_i), with the token-causal mask zeroing inside the
-    diagonal block (exp(NEG_INF - L) underflows to exactly 0)."""
+    diagonal block (exp(NEG_INF - L) underflows to exactly 0). lse_col is
+    the (bq, 1) column of L_i."""
     sij = _dot(q, kk, trans_b=True) * scale
     if causal:
         rows = i * block_q + jax.lax.broadcasted_iota(
@@ -42,13 +46,13 @@ def _recompute_p(q, kk, lse_row, *, scale, causal, i, j, block_q, block_kv):
         cols = j * block_kv + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_kv), 1)
         sij = jnp.where(rows >= cols, sij, NEG_INF)
-    return jnp.exp(sij - lse_row[:, None])
+    return jnp.exp(sij - lse_col)
 
 
 def _dq_kernel(lut_ref, counts_ref,
                q_ref, k_ref, v_ref, do_ref, lse_ref, ds_ref,
                dq_ref, dq_acc,
-               *, scale: float, k_sel: int, causal: bool,
+               *, scale: float, tm: int, k_sel: int, causal: bool,
                block_q: int, block_kv: int):
     bh, i, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -58,14 +62,14 @@ def _dq_kernel(lut_ref, counts_ref,
 
     @pl.when(s < counts_ref[bh, i])
     def _step():
-        j = lut_ref[bh, i, s]
+        j = lut_ref[(bh * tm + i) * k_sel + s]
         q = q_ref[0].astype(jnp.float32)
         kk = k_ref[0].astype(jnp.float32)
-        p = _recompute_p(q, kk, lse_ref[0, 0], scale=scale, causal=causal,
+        p = _recompute_p(q, kk, lse_ref[0], scale=scale, causal=causal,
                          i=i, j=j, block_q=block_q, block_kv=block_kv)
         do = do_ref[0].astype(jnp.float32)
         dp = _dot(do, v_ref[0].astype(jnp.float32), trans_b=True)
-        dsij = p * (dp - ds_ref[0, 0][:, None]) * scale
+        dsij = p * (dp - ds_ref[0]) * scale
         dq_acc[...] += _dot(dsij, kk)
 
     @pl.when(s == k_sel - 1)
@@ -76,7 +80,7 @@ def _dq_kernel(lut_ref, counts_ref,
 def _dkv_kernel(col_lut_ref, col_counts_ref,
                 q_ref, k_ref, v_ref, do_ref, lse_ref, ds_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc,
-                *, scale: float, w_col: int, causal: bool,
+                *, scale: float, tn: int, w_col: int, causal: bool,
                 block_q: int, block_kv: int):
     bh, j, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -87,15 +91,15 @@ def _dkv_kernel(col_lut_ref, col_counts_ref,
 
     @pl.when(c < col_counts_ref[bh, j])
     def _step():
-        i = col_lut_ref[bh, j, c]
+        i = col_lut_ref[(bh * tn + j) * w_col + c]
         q = q_ref[0].astype(jnp.float32)
         kk = k_ref[0].astype(jnp.float32)
-        p = _recompute_p(q, kk, lse_ref[0, 0], scale=scale, causal=causal,
+        p = _recompute_p(q, kk, lse_ref[0], scale=scale, causal=causal,
                          i=i, j=j, block_q=block_q, block_kv=block_kv)
         do = do_ref[0].astype(jnp.float32)
         dv_acc[...] += _dot(p, do, trans_a=True)
         dp = _dot(do, v_ref[0].astype(jnp.float32), trans_b=True)
-        dsij = p * (dp - ds_ref[0, 0][:, None]) * scale
+        dsij = p * (dp - ds_ref[0]) * scale
         dk_acc[...] += _dot(dsij, q, trans_a=True)
 
     @pl.when(c == w_col - 1)
@@ -104,44 +108,68 @@ def _dkv_kernel(col_lut_ref, col_counts_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _launch_runs(make_spec, kern, lut, counts, q, k, v, do_s, lse, d_s,
+                 *, n_out, per_head, interpret):
+    """Launch the kernel over head runs that fit SMEM (`head_runs`).
+    `make_spec(h0, hr)` gives the grid spec whose index maps add h0, so
+    operands pass whole and only the flat LUT and counts are sliced;
+    lse and d_s enter as (BH, N, 1) columns. Returns `n_out` (BH, N, D)
+    f32 outputs."""
+    bh_q, n, d = q.shape
+    runs = head_runs(bh_q, bh_q // k.shape[0], per_head)
+    hr = runs[0][1]
+    outs = [pl.pallas_call(
+        kern, grid_spec=make_spec(h0, hr),
+        out_shape=[jax.ShapeDtypeStruct((hr, n, d), jnp.float32)] * n_out,
+        interpret=interpret,
+    )(lut[h0:h0 + hr].reshape(-1), counts[h0:h0 + hr], q, k, v, do_s,
+      lse[..., None], d_s[..., None]) for h0, _ in runs]
+    return tuple(jnp.concatenate(x) for x in zip(*outs))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "causal", "block_q", "block_kv", "interpret"))
 def sla_bwd_dq(lut, counts, q, k, v, do_s, lse, d_s, *, scale, causal,
-               block_q, block_kv, interpret=True):
+               block_q, block_kv, interpret=None):
     """dQ of the sparse component. Shapes as in sla_fwd; d_s=(BH,N) rowsum
-    (dO^s . O^s). Returns dq (BH, N, D) f32."""
+    (dO^s . O^s). lse and d_s enter the kernel as (BH, N, 1) columns, so
+    their blocks are Mosaic-tileable. Returns dq (BH, N, D) f32."""
+    if interpret is None:
+        interpret = default_interpret()
     bh_q, n, d = q.shape
     group = bh_q // k.shape[0]
     tm = n // block_q
     k_sel = lut.shape[-1]
 
-    def kv_map(bh, i, s, lut_ref, counts_ref):
-        return (bh // group, lut_ref[bh, i, s], 0)
+    def make_spec(h0, hr):
+        def row(bh, i, s, *_):
+            return (h0 + bh, i, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bh_q, tm, k_sel),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_: (bh, i, 0)),   # q
-            pl.BlockSpec((1, block_kv, d), kv_map),                           # k
-            pl.BlockSpec((1, block_kv, d), kv_map),                           # v
-            pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_: (bh, i, 0)),   # do
-            pl.BlockSpec((1, 1, block_q), lambda bh, i, s, *_: (bh, 0, i)),   # lse
-            pl.BlockSpec((1, 1, block_q), lambda bh, i, s, *_: (bh, 0, i)),   # d_s
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_: (bh, i, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-    )
-    kern = functools.partial(_dq_kernel, scale=scale, k_sel=k_sel,
+        def kv_map(bh, i, s, lut_ref, counts_ref):
+            return ((h0 + bh) // group, lut_ref[(bh * tm + i) * k_sel + s], 0)
+
+        return pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(hr, tm, k_sel),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), row),                   # q
+                pl.BlockSpec((1, block_kv, d), kv_map),               # k
+                pl.BlockSpec((1, block_kv, d), kv_map),               # v
+                pl.BlockSpec((1, block_q, d), row),                   # do
+                pl.BlockSpec((1, block_q, 1), row),                   # lse
+                pl.BlockSpec((1, block_q, 1), row),                   # d_s
+            ],
+            out_specs=[pl.BlockSpec((1, block_q, d),
+                                    lambda bh, i, s, *_: (bh, i, 0))],
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        )
+
+    kern = functools.partial(_dq_kernel, scale=scale, tm=tm, k_sel=k_sel,
                              causal=causal, block_q=block_q, block_kv=block_kv)
-    (dq,) = pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bh_q, n, d), jnp.float32)],
-        interpret=interpret,
-    )(lut, counts, q, k, v, do_s, lse[:, None, :], d_s[:, None, :])
+    (dq,) = _launch_runs(make_spec, kern, lut, counts, q, k, v, do_s, lse,
+                         d_s, n_out=1, per_head=tm * k_sel,
+                         interpret=interpret)
     return dq
 
 
@@ -149,52 +177,50 @@ def sla_bwd_dq(lut, counts, q, k, v, do_s, lse, d_s, *, scale, causal,
     jax.jit,
     static_argnames=("scale", "causal", "block_q", "block_kv", "interpret"))
 def sla_bwd_dkv(col_lut, col_counts, q, k, v, do_s, lse, d_s, *, scale,
-                causal, block_q, block_kv, interpret=True):
+                causal, block_q, block_kv, interpret=None):
     """dK, dV of the sparse component via the column LUT.
 
     k, v may be GQA-shared: (BH_kv, N, D). The kernel runs per *query* head
     (grid BH) and the wrapper reduces over the head group afterwards.
     Returns (dk, dv): (BH, N, D) f32 (per query head — caller group-sums).
     """
+    if interpret is None:
+        interpret = default_interpret()
     bh_q, n, d = q.shape
     group = bh_q // k.shape[0]
     tn = n // block_kv
     w_col = col_lut.shape[-1]
 
-    def row_map(bh, j, c, col_lut_ref, col_counts_ref):
-        return (bh, col_lut_ref[bh, j, c], 0)
+    def make_spec(h0, hr):
+        def row_map(bh, j, c, col_lut_ref, col_counts_ref):
+            return (h0 + bh, col_lut_ref[(bh * tn + j) * w_col + c], 0)
 
-    def row_map_lse(bh, j, c, col_lut_ref, col_counts_ref):
-        return (bh, 0, col_lut_ref[bh, j, c])
+        def kv(bh, j, c, *_):
+            return ((h0 + bh) // group, j, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bh_q, tn, w_col),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), row_map),                            # q
-            pl.BlockSpec((1, block_kv, d),
-                         lambda bh, j, c, *_: (bh // group, j, 0)),            # k
-            pl.BlockSpec((1, block_kv, d),
-                         lambda bh, j, c, *_: (bh // group, j, 0)),            # v
-            pl.BlockSpec((1, block_q, d), row_map),                            # do
-            pl.BlockSpec((1, 1, block_q), row_map_lse),                        # lse
-            pl.BlockSpec((1, 1, block_q), row_map_lse),                        # d_s
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_kv, d), lambda bh, j, c, *_: (bh, j, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda bh, j, c, *_: (bh, j, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d), jnp.float32),
-        ],
-    )
-    kern = functools.partial(_dkv_kernel, scale=scale, w_col=w_col,
+        def out(bh, j, c, *_):
+            return (bh, j, 0)
+
+        return pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(hr, tn, w_col),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), row_map),               # q
+                pl.BlockSpec((1, block_kv, d), kv),                   # k
+                pl.BlockSpec((1, block_kv, d), kv),                   # v
+                pl.BlockSpec((1, block_q, d), row_map),               # do
+                pl.BlockSpec((1, block_q, 1), row_map),               # lse
+                pl.BlockSpec((1, block_q, 1), row_map),               # d_s
+            ],
+            out_specs=[pl.BlockSpec((1, block_kv, d), out)] * 2,
+            scratch_shapes=[
+                pltpu.VMEM((block_kv, d), jnp.float32),
+                pltpu.VMEM((block_kv, d), jnp.float32),
+            ],
+        )
+
+    kern = functools.partial(_dkv_kernel, scale=scale, tn=tn, w_col=w_col,
                              causal=causal, block_q=block_q, block_kv=block_kv)
-    dk, dv = pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bh_q, n, d), jnp.float32),
-                   jax.ShapeDtypeStruct((bh_q, n, d), jnp.float32)],
-        interpret=interpret,
-    )(col_lut, col_counts, q, k, v, do_s, lse[:, None, :], d_s[:, None, :])
-    return dk, dv
+    return _launch_runs(make_spec, kern, col_lut, col_counts, q, k, v, do_s,
+                        lse, d_s, n_out=2, per_head=tn * w_col,
+                        interpret=interpret)

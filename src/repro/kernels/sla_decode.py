@@ -22,9 +22,9 @@ gather backend's math — so learned-routing gradients flow through the
 plan's marginal aggregation with the gather backend's contract. Integer
 plan inputs (lut / cnt / marg / positions) get float0 tangents.
 
-On hosts without a TPU the kernel runs in Pallas interpret mode (see
-`backends._decode_kernel_backend` for the one-line warning); numerics
-are identical either way: f32 accumulation, bf16 inputs cast on load.
+Where JAX's default backend is the CPU the kernel runs in Pallas
+interpret mode (`repro.kernels.mode.default_interpret`); numerics are
+identical either way: f32 accumulation, bf16 inputs cast on load.
 """
 from __future__ import annotations
 
@@ -38,6 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import reference as ref
 from repro.core.config import SLAConfig
+from repro.kernels.mode import default_interpret
 
 NEG_INF = -1e30
 EPS = 1e-6
@@ -67,7 +68,7 @@ def _decode_kernel(lut_ref, cnt_ref, marg_ref, pos_ref,  # scalar prefetch
 
     @pl.when(s < cnt_ref[bh, c])
     def _step():
-        q = q_ref[0].astype(jnp.float32)                  # (1, d)
+        q = q_ref[0, 0].astype(jnp.float32)               # (1, d)
         kk = k_ref[0, 0].astype(jnp.float32)              # (bkv, d)
         sij = _dot(q, kk, trans_b=True) * scale           # (1, bkv)
         j = lut_ref[bh, c, s]
@@ -90,23 +91,85 @@ def _decode_kernel(lut_ref, cnt_ref, marg_ref, pos_ref,  # scalar prefetch
         # single-token decode hd/zd == the streamed block, a no-op)
         is_diag = j == (pos_ref[bh] + c) // block_kv
         hsel_ref[...] += jnp.where(is_diag, hd_ref[0, 0], hb_ref[0, 0])
-        zsel_ref[...] += jnp.where(is_diag, zd_ref[0], zb_ref[0])
+        zsel_ref[...] += jnp.where(is_diag, zd_ref[0, 0], zb_ref[0, 0])
 
     @pl.when(s == k_sel - 1)
     def _finalize():
         l = l_ref[:, 0]
         alive = l > 0.0
-        os_ref[0] = (acc_ref[...]
-                     / jnp.where(alive, l, 1.0)[:, None]).astype(os_ref.dtype)
+        os_ref[0, 0] = (acc_ref[...] / jnp.where(alive, l, 1.0)[:, None]
+                        ).astype(os_ref.dtype)
         # subtractive marginal linear branch against the H/Z totals
-        qp = qp_ref[0].astype(jnp.float32)                # (1, d)
+        qp = qp_ref[0, 0].astype(jnp.float32)             # (1, d)
         h_m = ht_ref[0, 0] - hsel_ref[...]                # (d, d)
-        z_m = zt_ref[0] - zsel_ref[...]                   # (1, d)
+        z_m = zt_ref[0, 0] - zsel_ref[...]                # (1, d)
         num = _dot(qp, h_m)                               # (1, d)
         den = jnp.sum(qp * z_m, axis=-1, keepdims=True)   # (1, 1)
         live = jnp.logical_and(den > EPS, marg_ref[bh, c] > 0)
         ol = jnp.where(live, num / jnp.where(live, den, 1.0), 0.0)
-        ol_ref[0] = ol.astype(ol_ref.dtype)
+        ol_ref[0, 0] = ol.astype(ol_ref.dtype)
+
+
+def _slab(x):
+    """(..., D) -> (..., 1, D): a per-row vector becomes a (1, D) slab, the
+    block shape Mosaic tiles (last two block dims equal the array's)."""
+    return x[..., None, :]
+
+
+def _decode_call(kern, prefetch, operands, *, page_map, block_kv, group,
+                 interpret):
+    """One pallas_call for both decode layouts. `prefetch` holds the
+    scalar-prefetch operands (LUT first); `page_map(bh, c, s, *refs)`
+    names the (kv-head, page) each LUT entry streams K/V/h/z from.
+
+    Per-row vectors (q, qp, zblk, zdiag, ztot, outputs) travel as (1, D)
+    slabs so every block's last two dims are the array's own, as Mosaic
+    requires. Returns (o_s, o_l), both (BH, C, D) f32."""
+    q, qp, k, v, hblk, zblk, hdiag, zdiag, htot, ztot = operands
+    bh, c, k_sel = prefetch[0].shape
+    d = q.shape[-1]
+
+    def kv_map(*a):
+        return (*page_map(*a), 0, 0)
+
+    def tok_map(bh_i, c_i, s, *_):
+        return (bh_i, c_i, 0, 0)
+
+    def kv_tok_map(bh_i, c_i, s, *_):
+        return (bh_i // group, c_i, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(bh, c, k_sel),
+        in_specs=[
+            pl.BlockSpec((1, 1, 1, d), tok_map),                 # q
+            pl.BlockSpec((1, 1, 1, d), tok_map),                 # qp
+            pl.BlockSpec((1, 1, block_kv, d), kv_map),           # k
+            pl.BlockSpec((1, 1, block_kv, d), kv_map),           # v
+            pl.BlockSpec((1, 1, d, d), kv_map),                  # hblk
+            pl.BlockSpec((1, 1, 1, d), kv_map),                  # zblk
+            pl.BlockSpec((1, 1, d, d), kv_tok_map),              # hdiag
+            pl.BlockSpec((1, 1, 1, d), kv_tok_map),              # zdiag
+            pl.BlockSpec((1, 1, d, d), kv_tok_map),              # htot
+            pl.BlockSpec((1, 1, 1, d), kv_tok_map),              # ztot
+        ],
+        out_specs=[pl.BlockSpec((1, 1, 1, d), tok_map)] * 2,
+        scratch_shapes=[
+            pltpu.VMEM((1, d), jnp.float32),       # acc
+            pltpu.VMEM((1, LANES), jnp.float32),   # m
+            pltpu.VMEM((1, LANES), jnp.float32),   # l
+            pltpu.VMEM((d, d), jnp.float32),       # hsel
+            pltpu.VMEM((1, d), jnp.float32),       # zsel
+        ],
+    )
+    o_s, o_l = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((bh, c, 1, d), jnp.float32)] * 2,
+        interpret=interpret,
+    )(*prefetch, _slab(q), _slab(qp), k, v, hblk, _slab(zblk), hdiag,
+      _slab(zdiag), htot, _slab(ztot))
+    return o_s[:, :, 0], o_l[:, :, 0]
 
 
 @functools.partial(
@@ -122,60 +185,17 @@ def _fused_decode(lut, cnt, marg, posv, q, qp, k, v, hblk, zblk,
     Tn, D); hdiag/htot: per-token snapshots (BH_kv, C, D, D);
     zdiag/ztot: (BH_kv, C, D). Returns (o_s, o_l) both (BH, C, D) f32.
     """
-    bh, c, k_sel = lut.shape
-    d = q.shape[-1]
-    grid = (bh, c, k_sel)
+    kern = functools.partial(_decode_kernel, scale=scale,
+                             k_sel=lut.shape[-1], block_kv=block_kv)
 
-    kern = functools.partial(
-        _decode_kernel, scale=scale, k_sel=k_sel, block_kv=block_kv)
+    def page_map(bh_i, c_i, s, lut_ref, *_):
+        return (bh_i // group, lut_ref[bh_i, c_i, s])
 
-    def kv_map(bh_i, c_i, s, lut_ref, *_):
-        return (bh_i // group, lut_ref[bh_i, c_i, s], 0, 0)
-
-    def z_map(bh_i, c_i, s, lut_ref, *_):
-        return (bh_i // group, lut_ref[bh_i, c_i, s], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_:
-                         (bh_i, c_i, 0)),                        # q
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_:
-                         (bh_i, c_i, 0)),                        # qp
-            pl.BlockSpec((1, 1, block_kv, d), kv_map),           # k
-            pl.BlockSpec((1, 1, block_kv, d), kv_map),           # v
-            pl.BlockSpec((1, 1, d, d), kv_map),                  # hblk
-            pl.BlockSpec((1, 1, d), z_map),                      # zblk
-            pl.BlockSpec((1, 1, d, d), lambda bh_i, c_i, s, *_:
-                         (bh_i // group, c_i, 0, 0)),            # hdiag
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_:
-                         (bh_i // group, c_i, 0)),               # zdiag
-            pl.BlockSpec((1, 1, d, d), lambda bh_i, c_i, s, *_:
-                         (bh_i // group, c_i, 0, 0)),            # htot
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_:
-                         (bh_i // group, c_i, 0)),               # ztot
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_: (bh_i, c_i, 0)),
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_: (bh_i, c_i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),       # acc
-            pltpu.VMEM((1, LANES), jnp.float32),   # m
-            pltpu.VMEM((1, LANES), jnp.float32),   # l
-            pltpu.VMEM((d, d), jnp.float32),       # hsel
-            pltpu.VMEM((1, d), jnp.float32),       # zsel
-        ],
-    )
-
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bh, c, d), jnp.float32)] * 2,
-        interpret=interpret,
-    )(lut, cnt, marg, posv, q, qp, k, v, hblk, zblk, hdiag, zdiag,
-      htot, ztot)
+    return _decode_call(
+        kern, (lut, cnt, marg, posv),
+        (q, qp, k, v, hblk, zblk, hdiag, zdiag, htot, ztot),
+        page_map=page_map, block_kv=block_kv, group=group,
+        interpret=interpret)
 
 
 def _decode_kernel_paged(lut_ref, plut_ref, cnt_ref, marg_ref, pos_ref,
@@ -205,60 +225,17 @@ def _fused_decode_paged(lut, plut, cnt, marg, posv, q, qp, k, v, hblk, zblk,
     them as ((bh // group) % hkv, page); per-token operands (q/qp,
     hdiag/zdiag, htot/ztot) keep the flat (B*H / B*Hkv, C, ...) layout
     of `_fused_decode`. Returns (o_s, o_l) both (BH, C, D) f32."""
-    bh, c, k_sel = lut.shape
-    d = q.shape[-1]
-    grid = (bh, c, k_sel)
+    kern = functools.partial(_decode_kernel_paged, scale=scale,
+                             k_sel=lut.shape[-1], block_kv=block_kv)
 
-    kern = functools.partial(
-        _decode_kernel_paged, scale=scale, k_sel=k_sel, block_kv=block_kv)
+    def page_map(bh_i, c_i, s, lut_ref, plut_ref, *_):
+        return ((bh_i // group) % hkv, plut_ref[bh_i, c_i, s])
 
-    def kv_map(bh_i, c_i, s, lut_ref, plut_ref, *_):
-        return ((bh_i // group) % hkv, plut_ref[bh_i, c_i, s], 0, 0)
-
-    def z_map(bh_i, c_i, s, lut_ref, plut_ref, *_):
-        return ((bh_i // group) % hkv, plut_ref[bh_i, c_i, s], 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_:
-                         (bh_i, c_i, 0)),                        # q
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_:
-                         (bh_i, c_i, 0)),                        # qp
-            pl.BlockSpec((1, 1, block_kv, d), kv_map),           # k pool
-            pl.BlockSpec((1, 1, block_kv, d), kv_map),           # v pool
-            pl.BlockSpec((1, 1, d, d), kv_map),                  # hblk pool
-            pl.BlockSpec((1, 1, d), z_map),                      # zblk pool
-            pl.BlockSpec((1, 1, d, d), lambda bh_i, c_i, s, *_:
-                         (bh_i // group, c_i, 0, 0)),            # hdiag
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_:
-                         (bh_i // group, c_i, 0)),               # zdiag
-            pl.BlockSpec((1, 1, d, d), lambda bh_i, c_i, s, *_:
-                         (bh_i // group, c_i, 0, 0)),            # htot
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_:
-                         (bh_i // group, c_i, 0)),               # ztot
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_: (bh_i, c_i, 0)),
-            pl.BlockSpec((1, 1, d), lambda bh_i, c_i, s, *_: (bh_i, c_i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, d), jnp.float32),       # acc
-            pltpu.VMEM((1, LANES), jnp.float32),   # m
-            pltpu.VMEM((1, LANES), jnp.float32),   # l
-            pltpu.VMEM((d, d), jnp.float32),       # hsel
-            pltpu.VMEM((1, d), jnp.float32),       # zsel
-        ],
-    )
-
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bh, c, d), jnp.float32)] * 2,
-        interpret=interpret,
-    )(lut, plut, cnt, marg, posv, q, qp, k, v, hblk, zblk, hdiag, zdiag,
-      htot, ztot)
+    return _decode_call(
+        kern, (lut, plut, cnt, marg, posv),
+        (q, qp, k, v, hblk, zblk, hdiag, zdiag, htot, ztot),
+        page_map=page_map, block_kv=block_kv, group=group,
+        interpret=interpret)
 
 
 def _decode_attention_paged(state, qg, qpg, pos, cfg: SLAConfig, scale,
@@ -428,7 +405,7 @@ _decode_core.defvjp(_decode_core_fwd, _decode_core_bwd)
 # public entry
 # ---------------------------------------------------------------------------
 def decode_attention(state, qg, qpg, pos, cfg: SLAConfig, scale=None,
-                     interpret: bool = True):
+                     interpret: bool | None = None):
     """Fused decode attention for a chunk of C tokens.
 
     qg / qpg: (B, Hkv, G, C, D) grouped queries (C = 1 for single-token
@@ -440,8 +417,11 @@ def decode_attention(state, qg, qpg, pos, cfg: SLAConfig, scale=None,
     axis before K. `pos` is the base position: scalar or (B,) per-slot
     (token c sits at pos + c). Returns (o_s, o_l), both
     (B, Hkv, G, C, D) f32; gradients flow through q/qp/k/v/hblk/zblk/
-    htot/ztot via the gather-math VJP.
+    htot/ztot via the gather-math VJP. `interpret` None decides by the
+    default backend (`repro.kernels.mode.default_interpret`).
     """
+    if interpret is None:
+        interpret = default_interpret()
     if "pt" in state:
         return _decode_attention_paged(state, qg, qpg, pos, cfg, scale,
                                        interpret)

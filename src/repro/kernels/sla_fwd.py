@@ -21,9 +21,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import default_interpret
+
 NEG_INF = -1e30
 EPS = 1e-6
 LANES = 128
+# Scalar-prefetched LUT entries one pallas_call may hold. Scalar
+# prefetch lives in SMEM (1 MiB on TPU v5e), shared with the row counts
+# and the kernel's own scalars; a longer LUT is split over head runs.
+SMEM_LUT_ENTRIES = 128 * 1024
+
+
+def head_runs(bh, group, per_head):
+    """Split `bh` query heads into equal runs of whole GQA groups whose
+    flattened LUTs (`per_head` entries per head) fit SMEM_LUT_ENTRIES.
+    Returns [(h0, h1), ...]; one run when everything fits."""
+    n_kv = bh // group
+    m = next((m for m in range(n_kv, 0, -1)
+              if n_kv % m == 0 and m * group * per_head <= SMEM_LUT_ENTRIES),
+             1)
+    return [(h0, h0 + m * group) for h0 in range(0, bh, m * group)]
 
 
 def _dot(a, b, trans_b=False):
@@ -35,7 +52,7 @@ def _fwd_kernel(lut_ref, counts_ref, base_ref,  # scalar prefetch
                 q_ref, k_ref, v_ref, qp_ref, hi_ref, zi_ref,  # inputs
                 os_ref, ol_ref, lse_ref,  # outputs
                 acc_ref, m_ref, l_ref,  # VMEM scratch
-                *, scale: float, k_sel: int, causal: bool,
+                *, scale: float, tm: int, k_sel: int, causal: bool,
                 block_q: int, block_kv: int):
     bh, i, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -51,7 +68,7 @@ def _fwd_kernel(lut_ref, counts_ref, base_ref,  # scalar prefetch
         kk = k_ref[0].astype(jnp.float32)
         sij = _dot(q, kk, trans_b=True) * scale  # (bq, bkv) f32
         if causal:
-            j = lut_ref[bh, i, s]
+            j = lut_ref[(bh * tm + i) * k_sel + s]
             rows = (base_ref[0] + i) * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 0)
             cols = j * block_kv + jax.lax.broadcasted_iota(
@@ -70,13 +87,13 @@ def _fwd_kernel(lut_ref, counts_ref, base_ref,  # scalar prefetch
 
     @pl.when(s == k_sel - 1)
     def _finalize():
-        m, l = m_ref[:, 0], l_ref[:, 0]
-        os_ref[0] = (acc_ref[...] / l[:, None]).astype(os_ref.dtype)
-        lse_ref[0] = (m + jnp.log(l))[None, :].astype(lse_ref.dtype)
+        m, l = m_ref[:, :1], l_ref[:, :1]  # (bq, 1) columns
+        os_ref[0] = (acc_ref[...] / l).astype(os_ref.dtype)
+        lse_ref[0] = (m + jnp.log(l)).astype(lse_ref.dtype)
         # Linear branch (Eq. 5): one (bq,d)x(d,d) matmul + normalizer.
         qp = qp_ref[0].astype(jnp.float32)
         num = _dot(qp, hi_ref[0, 0])
-        den = _dot(qp, zi_ref[0, 0][:, None])  # (bq, 1)
+        den = _dot(qp, zi_ref[0, 0], trans_b=True)  # (bq,d)x(1,d)^T: (bq, 1)
         live = den > EPS
         ol = jnp.where(live, num / jnp.where(live, den, 1.0), 0.0)
         ol_ref[0] = ol.astype(ol_ref.dtype)
@@ -86,7 +103,7 @@ def _fwd_kernel(lut_ref, counts_ref, base_ref,  # scalar prefetch
     jax.jit,
     static_argnames=("scale", "causal", "block_q", "block_kv", "interpret"))
 def sla_fwd(lut, counts, q, k, v, qp, hi, zi, *, scale, causal,
-            block_q, block_kv, interpret=True, base=None):
+            block_q, block_kv, interpret=None, base=None):
     """Run the fused forward kernel.
 
     Args:
@@ -99,9 +116,18 @@ def sla_fwd(lut, counts, q, k, v, qp, hi, zi, *, scale, causal,
         shifts the causal mask so a chunked-prefill span attends its
         true positions; TRACED (scalar-prefetched), so every chunk
         index shares one compiled kernel.
+      interpret: Pallas interpret mode; None decides by the default
+        backend (`repro.kernels.mode.default_interpret`).
 
     Returns: (o_s (BH,N,D) f32, o_l (BH,N,D) f32, lse (BH,N) f32)
+
+    Every block's last two dims are (8, 128)-tileable or the array's
+    full dims, as Mosaic requires: zi travels as a (1, D) slab per row
+    and lse as an (N, 1) column. The LUT is scalar-prefetched flat, and
+    heads run in as many launches as `head_runs` needs to fit it in SMEM.
     """
+    if interpret is None:
+        interpret = default_interpret()
     if base is None:
         base = jnp.zeros((1,), jnp.int32)
     bh_q, n, d = q.shape
@@ -109,45 +135,62 @@ def sla_fwd(lut, counts, q, k, v, qp, hi, zi, *, scale, causal,
     group = bh_q // bh_kv
     tm = n // block_q
     k_sel = lut.shape[-1]
-    grid = (bh_q, tm, k_sel)
 
     kern = functools.partial(
-        _fwd_kernel, scale=scale, k_sel=k_sel, causal=causal,
+        _fwd_kernel, scale=scale, tm=tm, k_sel=k_sel, causal=causal,
         block_q=block_q, block_kv=block_kv)
+    runs = head_runs(bh_q, group, tm * k_sel)
+    hr = runs[0][1]
 
-    def kv_map(bh, i, s, lut_ref, counts_ref, base_ref):
-        return (bh // group, lut_ref[bh, i, s], 0)
+    def launch(h0):
+        """One pallas_call over heads [h0, h0 + hr): the index maps add
+        h0, so operands are passed whole and only the LUT is sliced."""
+        def row(bh, i, s, *_):
+            return (h0 + bh, i, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_: (bh, i, 0)),  # q
-            pl.BlockSpec((1, block_kv, d), kv_map),                          # k
-            pl.BlockSpec((1, block_kv, d), kv_map),                          # v
-            pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_: (bh, i, 0)),  # qp
-            pl.BlockSpec((1, 1, d, d), lambda bh, i, s, *_: (bh, i, 0, 0)),  # hi
-            pl.BlockSpec((1, 1, d), lambda bh, i, s, *_: (bh, i, 0)),        # zi
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_: (bh, i, 0)),  # o_s
-            pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_: (bh, i, 0)),  # o_l
-            pl.BlockSpec((1, 1, block_q), lambda bh, i, s, *_: (bh, 0, i)),  # lse
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),      # acc
-            pltpu.VMEM((block_q, LANES), jnp.float32),  # m (lane-broadcast)
-            pltpu.VMEM((block_q, LANES), jnp.float32),  # l (lane-broadcast)
-        ],
-    )
-    o_s, o_l, lse = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((bh_q, n, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh_q, n, d), jnp.float32),
-            jax.ShapeDtypeStruct((bh_q, 1, n), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lut, counts, base, q, k, v, qp, hi, zi)
-    return o_s, o_l, lse[:, 0, :]
+        def kv_map(bh, i, s, lut_ref, counts_ref, base_ref):
+            return ((h0 + bh) // group,
+                    lut_ref[(bh * tm + i) * k_sel + s], 0)
+
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(hr, tm, k_sel),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), row),                     # q
+                pl.BlockSpec((1, block_kv, d), kv_map),                 # k
+                pl.BlockSpec((1, block_kv, d), kv_map),                 # v
+                pl.BlockSpec((1, block_q, d), row),                     # qp
+                pl.BlockSpec((1, 1, d, d), lambda bh, i, s, *_:
+                             (h0 + bh, i, 0, 0)),                       # hi
+                pl.BlockSpec((1, 1, 1, d), lambda bh, i, s, *_:
+                             (h0 + bh, i, 0, 0)),                       # zi
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_:
+                             (bh, i, 0)),                               # o_s
+                pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_:
+                             (bh, i, 0)),                               # o_l
+                pl.BlockSpec((1, block_q, 1), lambda bh, i, s, *_:
+                             (bh, i, 0)),                               # lse
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),      # acc
+                pltpu.VMEM((block_q, LANES), jnp.float32),  # m (lane-bcast)
+                pltpu.VMEM((block_q, LANES), jnp.float32),  # l (lane-bcast)
+            ],
+        )
+        return pl.pallas_call(
+            kern,
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((hr, n, d), jnp.float32),
+                jax.ShapeDtypeStruct((hr, n, d), jnp.float32),
+                jax.ShapeDtypeStruct((hr, n, 1), jnp.float32),
+            ],
+            interpret=interpret,
+        )(lut[h0:h0 + hr].reshape(-1), counts[h0:h0 + hr], base,
+          q, k, v, qp, hi, zi[:, :, None, :])
+
+    outs = [launch(h0) for h0, _ in runs]
+    o_s, o_l, lse = (jnp.concatenate(x) for x in zip(*outs))
+    return o_s, o_l, lse[:, :, 0]

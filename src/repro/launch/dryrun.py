@@ -22,6 +22,7 @@ from repro.configs import (ASSIGNED_ARCHS, PAPER_ARCHS, get_arch,
 from repro.configs.base import DIT_SHAPES, SHAPES  # noqa: E402
 from repro.distributed.sharding import (batch_shardings, cache_shardings,
                                         param_shardings)  # noqa: E402
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.steps import (abstract_state, make_prefill_step,
                                 make_serve_step,
@@ -170,8 +171,7 @@ def main():
 
     out_dir = pathlib.Path(args.out)
     out_dir.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir",
-                      str(out_dir / ".jax_cache"))
+    use_compile_cache()
 
     archs = (ASSIGNED_ARCHS if args.arch == "all" else [args.arch])
     if args.include_paper_archs and args.arch == "all":

@@ -57,6 +57,7 @@ import json
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch
@@ -79,7 +80,7 @@ def main(argv=None):
                     help="SLA execution backend from the core.backends "
                          "registry: 'gather' (LUT-gather XLA, true sparse "
                          "FLOPs — default), 'reference' (dense oracle), "
-                         "'kernel' (fused Pallas; interpret mode off-TPU). "
+                         "'kernel' (fused Pallas; interpreted on the CPU). "
                          "Unknown names fail loudly at startup")
     ap.add_argument("--scheduler", default="static",
                     choices=["static", "continuous"],
@@ -272,7 +273,15 @@ def main(argv=None):
             cfg, sla=cfg.sla.replace(col_capacity_factor=None))
     cfg.sla.validate()
     mdl = registry.get_model(cfg)
-    params = mdl.init(jax.random.PRNGKey(args.seed), cfg)
+    key = jax.random.PRNGKey(args.seed)
+    if args.workload == "dit":
+        # DiT serving stores its weights in bf16 (dit.forward casts each
+        # one at use): at wan2_1_1_3b's width f32 weights plus a
+        # 32k-token tick do not fit one 16 GB chip
+        params = jax.jit(lambda k: jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16), mdl.init(k, cfg)))(key)
+    else:
+        params = mdl.init(key, cfg)
     rs = np.random.default_rng(args.seed)
     max_len = args.prompt_len + args.max_new + 8
 
@@ -488,4 +497,7 @@ def _print_stats(args, st, n_done, wall, metrics, drift_threshold):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

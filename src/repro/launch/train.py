@@ -11,6 +11,7 @@ optional error-feedback gradient compression on the (pod-)DP axis.
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
@@ -137,16 +138,21 @@ def main(argv=None):
     mesh = make_host_mesh(args.data_mesh, args.model_mesh)
 
     rng = jax.random.PRNGKey(args.seed)
-    params = mdl.init(rng, cfg)
-    if args.routing_warm_init:
-        params = routing_warm_init(params)
-    opt_state = adamw.init(params)
+
+    def init_state(rng):
+        params = mdl.init(rng, cfg)
+        if args.routing_warm_init:
+            params = routing_warm_init(params)
+        return params, adamw.init(params)
+
+    # built straight into the mesh shardings: at published widths the
+    # f32 params + AdamW moments never exist whole on one device
     from jax.sharding import NamedSharding, PartitionSpec as P
-    p_shard = param_shardings(mesh, jax.eval_shape(lambda: params))
+    p_shard = param_shardings(mesh, jax.eval_shape(init_state, rng)[0])
     o_shard = {"m": p_shard, "v": p_shard,
                "step": NamedSharding(mesh, P())}
-    params = jax.device_put(params, p_shard)
-    opt_state = jax.device_put(opt_state, o_shard)
+    params, opt_state = jax.jit(
+        init_state, out_shardings=(p_shard, o_shard))(rng)
 
     mgr = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
     start_step = 0
@@ -187,11 +193,15 @@ def main(argv=None):
     def loss_of(p, batch):
         return loss_impl(p, cfg, batch)
 
-    @jax.jit
+    # gradients leave sharded like the params (reduce-scatter, not a
+    # replicated all-reduce): whole f32 gradients do not fit one chip
+    @functools.partial(jax.jit,
+                       out_shardings=(NamedSharding(mesh, P()), p_shard))
     def grad_step(p, batch):
         return jax.value_and_grad(loss_of)(p, batch)
 
-    @jax.jit
+    # params and optimizer state are donated: the update writes in place
+    @functools.partial(jax.jit, donate_argnums=(0, 2))
     def apply_update(p, g, o):
         return adamw.update(p, g, o, opt_cfg, trainable=mask)
 
@@ -239,4 +249,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -575,7 +575,6 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, carry, start,
     positions = jnp.broadcast_to(
         (start + jnp.arange(c, dtype=jnp.int32))[None, :], (b, c))
     x = jnp.take(params["embed"], tokens, axis=0).astype(compute_dtype)
-    interpret = jax.default_backend() != "tpu"  # kernel-backend parity
 
     def body(x, layer):
         layer = list(layer)
@@ -635,7 +634,7 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, carry, start,
         else:
             o_s, o_l = kops.sla_attention_rows(
                 q, krf, vrf, qp, kp, marginal, lut, counts, plan_cfg,
-                interpret=interpret, row_offset=sb)
+                row_offset=sb)
         proj = p["sla_proj"].astype(jnp.float32)
         o = (o_s + jnp.einsum("bhnd,hde->bhne", o_l, proj)).astype(x.dtype)
         out = o.transpose(0, 2, 1, 3).reshape(b, c, -1)

@@ -400,21 +400,9 @@ def comp_cost(name: str, comps: Dict[str, Computation],
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """XLA's own `compiled.cost_analysis()` with its cross-version shape
-    normalized: older jax returns one dict, newer returns a list with one
-    dict per partitioned executable. Always returns a flat {metric: value}
-    dict ({} when XLA reports nothing), so callers can index ["flops"]
-    regardless of the installed jax."""
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        out: Dict[str, float] = {}
-        for entry in ca:
-            for key, val in (entry or {}).items():
-                out[key] = out.get(key, 0.0) + float(val)
-        return out
-    return dict(ca)
+    """XLA's own `compiled.cost_analysis()` as a {metric: value} dict
+    ({} when XLA reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def analyze(hlo_text: str) -> Dict[str, float]:

@@ -82,13 +82,9 @@ def test_collectives_parsed_and_scaled(tmp_path):
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType
         from repro.roofline.hlo_cost import analyze
-        try:
-            from jax.sharding import AxisType
-            mesh = jax.make_mesh((4,), ("d",),
-                                 axis_types=(AxisType.Auto,))
-        except ImportError:
-            mesh = jax.make_mesh((4,), ("d",))
+        mesh = jax.make_mesh((4,), ("d",), axis_types=(AxisType.Auto,))
         w = jnp.ones((64, 64))
         def f(x):
             def body(c, _):
