@@ -155,7 +155,8 @@ def execute(
         o_s, _ = ref.sparse_component(q, k, v, plan.mc, cfg, scale)
         return o_s.astype(in_dtype)
 
-    qp, kp = phi(q, cfg.phi), phi(k, cfg.phi)
+    with jax.named_scope("sla.linear"):
+        qp, kp = phi(q, cfg.phi), phi(k, cfg.phi)
 
     if cfg.mode == "l_plus_s":
         o_s, _ = ref.sparse_component(q, k, v, plan.mc, cfg, scale)
@@ -167,9 +168,10 @@ def execute(
 
     o_s, o_l = get_backend(backend)(plan, q, k, v, qp, kp, cfg, scale)
 
-    proj = params["proj"].astype(jnp.float32)
-    o = o_s + jnp.einsum("bhnd,hde->bhne", o_l, proj)
-    return o.astype(in_dtype)
+    with jax.named_scope("sla.merge"):
+        proj = params["proj"].astype(jnp.float32)
+        o = o_s + jnp.einsum("bhnd,hde->bhne", o_l, proj)
+        return o.astype(in_dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -142,19 +142,21 @@ def sla_forward_gather(
     then covers only the chunk's query blocks)."""
     b, h, n, d = q.shape
     tn = plan.num_kv_blocks
-    o_s, _ = sparse_component_gather(q, k, v, plan.lut, plan.counts, cfg,
-                                     scale, chunk, row_offset)
+    with jax.named_scope("sla.sparse"):
+        o_s, _ = sparse_component_gather(q, k, v, plan.lut, plan.counts,
+                                         cfg, scale, chunk, row_offset)
 
-    kpb = kp.astype(jnp.float32).reshape(b, h, tn, cfg.block_kv, d)
-    vb = v.astype(jnp.float32).reshape(b, h, tn, cfg.block_kv, d)
-    hb = jnp.einsum("bhnkd,bhnke->bhnde", kpb, vb)
-    zb = jnp.sum(kpb, axis=-2)
-    a = plan.marginal
-    hi = jnp.einsum("bhmn,bhnde->bhmde", a, hb)
-    zi = jnp.einsum("bhmn,bhnd->bhmd", a, zb)
-    tm = plan.num_q_blocks
-    qpb = qp.astype(jnp.float32).reshape(b, h, tm, cfg.block_q, d)
-    num = jnp.einsum("bhmqd,bhmde->bhmqe", qpb, hi)
-    den = jnp.einsum("bhmqd,bhmd->bhmq", qpb, zi)[..., None]
-    o_l = _safe_div(num, den).reshape(b, h, n, d)
+    with jax.named_scope("sla.linear"):
+        kpb = kp.astype(jnp.float32).reshape(b, h, tn, cfg.block_kv, d)
+        vb = v.astype(jnp.float32).reshape(b, h, tn, cfg.block_kv, d)
+        hb = jnp.einsum("bhnkd,bhnke->bhnde", kpb, vb)
+        zb = jnp.sum(kpb, axis=-2)
+        a = plan.marginal
+        hi = jnp.einsum("bhmn,bhnde->bhmde", a, hb)
+        zi = jnp.einsum("bhmn,bhnd->bhmd", a, zb)
+        tm = plan.num_q_blocks
+        qpb = qp.astype(jnp.float32).reshape(b, h, tm, cfg.block_q, d)
+        num = jnp.einsum("bhmqd,bhmde->bhmqe", qpb, hi)
+        den = jnp.einsum("bhmqd,bhmd->bhmq", qpb, zi)[..., None]
+        o_l = _safe_div(num, den).reshape(b, h, n, d)
     return o_s, o_l

@@ -153,13 +153,17 @@ def plan_from_mask(mc: jax.Array, cfg: SLAConfig,
     — forward-identical to the hard indicator, but differentiable
     w.r.t. the routing parameters."""
     tm, tn = mc.shape[-2], mc.shape[-1]
-    lut, counts = build_lut(mc, cfg.num_critical(tn))
-    col_lut, col_counts = build_col_lut(
-        mc, cfg.col_capacity(tm, tn) if col_width is None else col_width)
-    if pc is not None and cfg.routing_mode == "learned":
-        marginal = routing_gates(pc, mc, cfg)
-    else:
-        marginal = (mc == 0).astype(jnp.float32)
+    with jax.named_scope("sla.plan"):
+        with jax.named_scope("lut"):
+            lut, counts = build_lut(mc, cfg.num_critical(tn))
+        with jax.named_scope("col_lut"):
+            col_lut, col_counts = build_col_lut(
+                mc, cfg.col_capacity(tm, tn) if col_width is None
+                else col_width)
+        if pc is not None and cfg.routing_mode == "learned":
+            marginal = routing_gates(pc, mc, cfg)
+        else:
+            marginal = (mc == 0).astype(jnp.float32)
     return SLAPlan(mc=mc, lut=lut, counts=counts,
                    col_lut=col_lut, col_counts=col_counts,
                    marginal=marginal)
@@ -182,13 +186,17 @@ def plan_attention(
     gradients to the routing parameters (DESIGN.md "Learned routing").
     """
     cfg.validate()  # typo'd knob strings die here, not deep in a trace
-    h = q.shape[1]
-    if k.shape[1] != h:
-        assert h % k.shape[1] == 0
-        k = jnp.repeat(k, h // k.shape[1], axis=1)
-    pc = score_map(routing, jax.lax.stop_gradient(q),
-                   jax.lax.stop_gradient(k), cfg, scale)
-    return plan_from_mask(classify_blocks(pc, cfg), cfg, pc=pc)
+    with jax.named_scope("sla.plan"):
+        h = q.shape[1]
+        if k.shape[1] != h:
+            assert h % k.shape[1] == 0
+            k = jnp.repeat(k, h // k.shape[1], axis=1)
+        with jax.named_scope("score"):
+            pc = score_map(routing, jax.lax.stop_gradient(q),
+                           jax.lax.stop_gradient(k), cfg, scale)
+        with jax.named_scope("classify"):
+            mc = classify_blocks(pc, cfg)
+        return plan_from_mask(mc, cfg, pc=pc)
 
 
 # ---------------------------------------------------------------------------
@@ -280,7 +288,8 @@ def plan_retention(
 
     Gradient-stopped like planning itself. Returns (B, H) float32.
     """
-    return _retention_and_fresh_mc(plan, q, k, cfg, scale, routing)[0]
+    with jax.named_scope("sla.plan"):
+        return _retention_and_fresh_mc(plan, q, k, cfg, scale, routing)[0]
 
 
 def _retention_and_fresh_mc(
@@ -304,17 +313,20 @@ def _retention_and_fresh_mc(
     q = jax.lax.stop_gradient(q)
     k = jax.lax.stop_gradient(k)
     learned = cfg.routing_mode == "learned"
-    pc = score_map(routing, q, k, cfg, scale)  # (B, H, Tm, Tn) f32
+    with jax.named_scope("score"):
+        pc = score_map(routing, q, k, cfg, scale)  # (B, H, Tm, Tn) f32
     if pc.shape[-2:] != plan.mc.shape[-2:]:
         raise ValueError(
             f"stale SLAPlan: plan is for {plan.mc.shape[-2:]} blocks but "
             f"(q, k) pool to {pc.shape[-2:]} — shapes must match to "
             f"measure drift")
-    stale = jnp.sum(pc * (plan.mc == 1), axis=(-2, -1))
-    mc_fresh = classify_blocks(pc, cfg)
-    fresh = jnp.sum(pc * (mc_fresh == 1), axis=(-2, -1))
-    r = stale / jnp.maximum(fresh, EPS)
-    return jnp.clip(r, 0.0, 1.0), mc_fresh, (pc if learned else None)
+    with jax.named_scope("classify"):
+        mc_fresh = classify_blocks(pc, cfg)
+    with jax.named_scope("drift"):
+        stale = jnp.sum(pc * (plan.mc == 1), axis=(-2, -1))
+        fresh = jnp.sum(pc * (mc_fresh == 1), axis=(-2, -1))
+        r = jnp.clip(stale / jnp.maximum(fresh, EPS), 0.0, 1.0)
+    return r, mc_fresh, (pc if learned else None)
 
 
 def plan_drift(
@@ -350,21 +362,24 @@ def refresh_plan(
 
     Returns (plan', retention_scalar f32, replanned bool).
     """
-    r, mc_fresh, pc = _retention_and_fresh_mc(plan, q, k, cfg, scale,
-                                              routing)
-    retention = jnp.min(r)
-    # threshold >= 1.0 means "never", even at the clipped drift == 1.0
-    # extreme — the docs' blind-reuse contract beats the >= comparison
-    replanned = jnp.logical_and((1.0 - retention) >= threshold,
-                                jnp.asarray(threshold) < 1.0)
-    # the drift metric already classified the fresh structure; the
-    # rebuild only derives LUTs from it (and is guaranteed to match the
-    # classification the decision was based on)
-    new_plan = jax.lax.cond(
-        replanned,
-        lambda ops: plan_from_mask(ops[0], cfg, pc=pc),
-        lambda ops: ops[1],
-        (mc_fresh, plan))
+    with jax.named_scope("sla.plan"):
+        r, mc_fresh, pc = _retention_and_fresh_mc(plan, q, k, cfg, scale,
+                                                  routing)
+        with jax.named_scope("drift"):
+            retention = jnp.min(r)
+            # threshold >= 1.0 means "never", even at the clipped
+            # drift == 1.0 extreme — the docs' blind-reuse contract
+            # beats the >= comparison
+            replanned = jnp.logical_and((1.0 - retention) >= threshold,
+                                        jnp.asarray(threshold) < 1.0)
+        # the drift metric already classified the fresh structure; the
+        # rebuild only derives LUTs from it (and is guaranteed to match
+        # the classification the decision was based on)
+        new_plan = jax.lax.cond(
+            replanned,
+            lambda ops: plan_from_mask(ops[0], cfg, pc=pc),
+            lambda ops: ops[1],
+            (mc_fresh, plan))
     return new_plan, retention, replanned
 
 
@@ -397,20 +412,22 @@ def refresh_plan_per_sample(
 
     Returns (plan', retention (B,), replanned (B,) bool).
     """
-    r, mc_fresh, pc = _retention_and_fresh_mc(plan, q, k, cfg, scale,
-                                              routing)
-    retention = jnp.min(r, axis=-1)  # (B,) — min over heads only
-    thr = jnp.broadcast_to(jnp.asarray(thresholds, jnp.float32),
-                           retention.shape)
-    replanned = jnp.logical_and((1.0 - retention) >= thr, thr < 1.0)
-    fresh = plan_from_mask(mc_fresh, cfg, pc=pc)
-
     def sel(new_leaf, old_leaf):
         m = replanned.reshape(
             replanned.shape + (1,) * (new_leaf.ndim - replanned.ndim))
         return jnp.where(m, new_leaf, old_leaf)
 
-    new_plan = jax.tree_util.tree_map(sel, fresh, plan)
+    with jax.named_scope("sla.plan"):
+        r, mc_fresh, pc = _retention_and_fresh_mc(plan, q, k, cfg, scale,
+                                                  routing)
+        with jax.named_scope("drift"):
+            retention = jnp.min(r, axis=-1)  # (B,) — min over heads only
+            thr = jnp.broadcast_to(jnp.asarray(thresholds, jnp.float32),
+                                   retention.shape)
+            replanned = jnp.logical_and((1.0 - retention) >= thr, thr < 1.0)
+        fresh = plan_from_mask(mc_fresh, cfg, pc=pc)
+        with jax.named_scope("drift"):
+            new_plan = jax.tree_util.tree_map(sel, fresh, plan)
     return new_plan, retention, replanned
 
 

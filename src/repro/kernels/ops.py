@@ -49,17 +49,19 @@ def _block(x, blk):
 
 def _hz_blocks(kp, v, block_kv):
     """Per-KV-block linear-attention state: h_j = phi(K_j)^T V_j, z_j."""
-    kpb = _block(kp.astype(jnp.float32), block_kv)
-    vb = _block(v.astype(jnp.float32), block_kv)
-    h = jnp.einsum("gnkd,gnke->gnde", kpb, vb)
-    z = jnp.sum(kpb, axis=-2)
+    with jax.named_scope("sla.linear"):
+        kpb = _block(kp.astype(jnp.float32), block_kv)
+        vb = _block(v.astype(jnp.float32), block_kv)
+        h = jnp.einsum("gnkd,gnke->gnde", kpb, vb)
+        z = jnp.sum(kpb, axis=-2)
     return h, z
 
 
 def _aggregate(a, h, z):
     """H_i = sum_{j marginal} h_j, Z_i likewise (dense-matmul form)."""
-    hi = jnp.einsum("gmn,gnde->gmde", a, h)
-    zi = jnp.einsum("gmn,gnd->gmd", a, z)
+    with jax.named_scope("sla.linear"):
+        hi = jnp.einsum("gmn,gnde->gmde", a, h)
+        zi = jnp.einsum("gmn,gnd->gmd", a, z)
     return hi, zi
 
 

@@ -109,11 +109,12 @@ def _dkv_kernel(col_lut_ref, col_counts_ref,
 
 
 def _launch_runs(make_spec, kern, lut, counts, q, k, v, do_s, lse, d_s,
-                 *, n_out, per_head, interpret):
+                 *, n_out, per_head, interpret, name):
     """Launch the kernel over head runs that fit SMEM (`head_runs`).
     `make_spec(h0, hr)` gives the grid spec whose index maps add h0, so
     operands pass whole and only the flat LUT and counts are sliced;
-    lse and d_s enter as (BH, N, 1) columns. Returns `n_out` (BH, N, D)
+    lse and d_s enter as (BH, N, 1) columns; `name` names the kernel in
+    compiled programs and profiles. Returns `n_out` (BH, N, D)
     f32 outputs."""
     bh_q, n, d = q.shape
     runs = head_runs(bh_q, bh_q // k.shape[0], per_head)
@@ -121,7 +122,7 @@ def _launch_runs(make_spec, kern, lut, counts, q, k, v, do_s, lse, d_s,
     outs = [pl.pallas_call(
         kern, grid_spec=make_spec(h0, hr),
         out_shape=[jax.ShapeDtypeStruct((hr, n, d), jnp.float32)] * n_out,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(lut[h0:h0 + hr].reshape(-1), counts[h0:h0 + hr], q, k, v, do_s,
       lse[..., None], d_s[..., None]) for h0, _ in runs]
     return tuple(jnp.concatenate(x) for x in zip(*outs))
@@ -169,7 +170,7 @@ def sla_bwd_dq(lut, counts, q, k, v, do_s, lse, d_s, *, scale, causal,
                              causal=causal, block_q=block_q, block_kv=block_kv)
     (dq,) = _launch_runs(make_spec, kern, lut, counts, q, k, v, do_s, lse,
                          d_s, n_out=1, per_head=tm * k_sel,
-                         interpret=interpret)
+                         interpret=interpret, name="sla_bwd_dq")
     return dq
 
 
@@ -223,4 +224,4 @@ def sla_bwd_dkv(col_lut, col_counts, q, k, v, do_s, lse, d_s, *, scale,
                              causal=causal, block_q=block_q, block_kv=block_kv)
     return _launch_runs(make_spec, kern, col_lut, col_counts, q, k, v, do_s,
                         lse, d_s, n_out=2, per_head=tn * w_col,
-                        interpret=interpret)
+                        interpret=interpret, name="sla_bwd_dkv")

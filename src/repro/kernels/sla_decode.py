@@ -117,7 +117,7 @@ def _slab(x):
 
 
 def _decode_call(kern, prefetch, operands, *, page_map, block_kv, group,
-                 interpret):
+                 interpret, name):
     """One pallas_call for both decode layouts. `prefetch` holds the
     scalar-prefetch operands (LUT first); `page_map(bh, c, s, *refs)`
     names the (kv-head, page) each LUT entry streams K/V/h/z from.
@@ -167,6 +167,7 @@ def _decode_call(kern, prefetch, operands, *, page_map, block_kv, group,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((bh, c, 1, d), jnp.float32)] * 2,
         interpret=interpret,
+        name=name,
     )(*prefetch, _slab(q), _slab(qp), k, v, hblk, _slab(zblk), hdiag,
       _slab(zdiag), htot, _slab(ztot))
     return o_s[:, :, 0], o_l[:, :, 0]
@@ -195,7 +196,7 @@ def _fused_decode(lut, cnt, marg, posv, q, qp, k, v, hblk, zblk,
         kern, (lut, cnt, marg, posv),
         (q, qp, k, v, hblk, zblk, hdiag, zdiag, htot, ztot),
         page_map=page_map, block_kv=block_kv, group=group,
-        interpret=interpret)
+        interpret=interpret, name="sla_decode")
 
 
 def _decode_kernel_paged(lut_ref, plut_ref, cnt_ref, marg_ref, pos_ref,
@@ -235,7 +236,7 @@ def _fused_decode_paged(lut, plut, cnt, marg, posv, q, qp, k, v, hblk, zblk,
         kern, (lut, plut, cnt, marg, posv),
         (q, qp, k, v, hblk, zblk, hdiag, zdiag, htot, ztot),
         page_map=page_map, block_kv=block_kv, group=group,
-        interpret=interpret)
+        interpret=interpret, name="sla_decode_paged")
 
 
 def _decode_attention_paged(state, qg, qpg, pos, cfg: SLAConfig, scale,

@@ -128,8 +128,6 @@ def sla_fwd(lut, counts, q, k, v, qp, hi, zi, *, scale, causal,
     """
     if interpret is None:
         interpret = default_interpret()
-    if base is None:
-        base = jnp.zeros((1,), jnp.int32)
     bh_q, n, d = q.shape
     bh_kv = k.shape[0]
     group = bh_q // bh_kv
@@ -188,9 +186,13 @@ def sla_fwd(lut, counts, q, k, v, qp, hi, zi, *, scale, causal,
                 jax.ShapeDtypeStruct((hr, n, 1), jnp.float32),
             ],
             interpret=interpret,
+            name="sla_fwd",
         )(lut[h0:h0 + hr].reshape(-1), counts[h0:h0 + hr], base,
           q, k, v, qp, hi, zi[:, :, None, :])
 
-    outs = [launch(h0) for h0, _ in runs]
-    o_s, o_l, lse = (jnp.concatenate(x) for x in zip(*outs))
-    return o_s, o_l, lse[:, :, 0]
+    with jax.named_scope("sla.sparse"):
+        if base is None:
+            base = jnp.zeros((1,), jnp.int32)
+        outs = [launch(h0) for h0, _ in runs]
+        o_s, o_l, lse = (jnp.concatenate(x) for x in zip(*outs))
+        return o_s, o_l, lse[:, :, 0]

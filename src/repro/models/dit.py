@@ -112,11 +112,12 @@ def forward(params, cfg: ArchConfig, latents, t,
     t = jnp.asarray(t, jnp.float32)
     if t.ndim == 0:
         t = jnp.broadcast_to(t, (latents.shape[0],))
-    x = jnp.einsum("bnp,pd->bnd", latents.astype(compute_dtype),
-                   params["patch_in"].astype(compute_dtype))
-    temb = jnp.einsum("be,ed->bd", _timestep_embedding(t * 1000.0),
-                      params["t_embed"].astype(jnp.float32))
-    temb = jax.nn.silu(temb).astype(compute_dtype)
+    with jax.named_scope("dit.embed"):
+        x = jnp.einsum("bnp,pd->bnd", latents.astype(compute_dtype),
+                       params["patch_in"].astype(compute_dtype))
+        temb = jnp.einsum("be,ed->bd", _timestep_embedding(t * 1000.0),
+                          params["t_embed"].astype(jnp.float32))
+        temb = jax.nn.silu(temb).astype(compute_dtype)
     b, n, d = x.shape
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     import dataclasses
@@ -148,15 +149,17 @@ def forward(params, cfg: ArchConfig, latents, t,
         else:
             retention = jnp.float32(1.0)
             replanned = jnp.bool_(False)
-        mod = jnp.einsum("bd,de->be", temb, p["ada"].astype(temb.dtype))
-        sh1, sc1, g1, sh2, sc2, g2 = jnp.split(mod, 6, axis=-1)
-        xn = rms_norm(x, p["ln1"]) * (1 + sc1[:, None]) + sh1[:, None]
-        q = jnp.einsum("bsd,de->bse", xn, p["wq"].astype(x.dtype)) \
-            .reshape(b, n, h, dh).transpose(0, 2, 1, 3)
-        k = jnp.einsum("bsd,de->bse", xn, p["wk"].astype(x.dtype)) \
-            .reshape(b, n, hkv, dh).transpose(0, 2, 1, 3)
-        v = jnp.einsum("bsd,de->bse", xn, p["wv"].astype(x.dtype)) \
-            .reshape(b, n, hkv, dh).transpose(0, 2, 1, 3)
+        with jax.named_scope("dit.adaln"):
+            mod = jnp.einsum("bd,de->be", temb, p["ada"].astype(temb.dtype))
+            sh1, sc1, g1, sh2, sc2, g2 = jnp.split(mod, 6, axis=-1)
+            xn = rms_norm(x, p["ln1"]) * (1 + sc1[:, None]) + sh1[:, None]
+        with jax.named_scope("dit.qkv"):
+            q = jnp.einsum("bsd,de->bse", xn, p["wq"].astype(x.dtype)) \
+                .reshape(b, n, h, dh).transpose(0, 2, 1, 3)
+            k = jnp.einsum("bsd,de->bse", xn, p["wk"].astype(x.dtype)) \
+                .reshape(b, n, hkv, dh).transpose(0, 2, 1, 3)
+            v = jnp.einsum("bsd,de->bse", xn, p["wv"].astype(x.dtype)) \
+                .reshape(b, n, hkv, dh).transpose(0, 2, 1, 3)
         routing = p.get("routing") if sla_cfg.routing_mode == "learned" \
             else None
         if plan_needed and layer_plan is None:
@@ -171,28 +174,36 @@ def forward(params, cfg: ArchConfig, latents, t,
                       causal=False, backend=backend,
                       plan=layer_plan if plan_needed else None,
                       routing=routing)
-        o = o.transpose(0, 2, 1, 3).reshape(b, n, h * dh)
-        x = ctx.shard_residual(
-            x + g1[:, None] * jnp.einsum("bse,ed->bsd", o,
-                                         p["wo"].astype(x.dtype)))
+        with jax.named_scope("dit.attn_out"):
+            o = o.transpose(0, 2, 1, 3).reshape(b, n, h * dh)
+            x = ctx.shard_residual(
+                x + g1[:, None] * jnp.einsum("bse,ed->bsd", o,
+                                             p["wo"].astype(x.dtype)))
         if cfg.cross_attn and cond is not None:
-            cx = cond.astype(x.dtype)
-            lc = cx.shape[1]
-            xq = jnp.einsum("bsd,de->bse", rms_norm(x, p["ln_x"]),
-                            p["xq"].astype(x.dtype)) \
-                .reshape(b, n, h, dh).transpose(0, 2, 1, 3)
-            xk = jnp.einsum("bsd,de->bse", cx, p["xk"].astype(x.dtype)) \
-                .reshape(b, lc, hkv, dh).transpose(0, 2, 1, 3)
-            xv = jnp.einsum("bsd,de->bse", cx, p["xv"].astype(x.dtype)) \
-                .reshape(b, lc, hkv, dh).transpose(0, 2, 1, 3)
-            xo = attention(None, xq, xk, xv, "full", sla_cfg, causal=False)
-            xo = xo.transpose(0, 2, 1, 3).reshape(b, n, h * dh)
-            x = x + jnp.einsum("bse,ed->bsd", xo, p["xo"].astype(x.dtype))
-        xn2 = rms_norm(x, p["ln2"]) * (1 + sc2[:, None]) + sh2[:, None]
-        hmid = jnp.einsum("bsd,df->bsf", xn2, p["mlp_wi"].astype(x.dtype))
-        g, u = jnp.split(hmid, 2, axis=-1)
-        x = ctx.shard_residual(x + g2[:, None] * jnp.einsum(
-            "bsf,fd->bsd", jax.nn.silu(g) * u, p["mlp_wo"].astype(x.dtype)))
+            with jax.named_scope("dit.cross_attn"):
+                cx = cond.astype(x.dtype)
+                lc = cx.shape[1]
+                xq = jnp.einsum("bsd,de->bse", rms_norm(x, p["ln_x"]),
+                                p["xq"].astype(x.dtype)) \
+                    .reshape(b, n, h, dh).transpose(0, 2, 1, 3)
+                xk = jnp.einsum("bsd,de->bse", cx, p["xk"].astype(x.dtype)) \
+                    .reshape(b, lc, hkv, dh).transpose(0, 2, 1, 3)
+                xv = jnp.einsum("bsd,de->bse", cx, p["xv"].astype(x.dtype)) \
+                    .reshape(b, lc, hkv, dh).transpose(0, 2, 1, 3)
+                xo = attention(None, xq, xk, xv, "full", sla_cfg,
+                               causal=False)
+                xo = xo.transpose(0, 2, 1, 3).reshape(b, n, h * dh)
+                x = x + jnp.einsum("bse,ed->bsd", xo,
+                                   p["xo"].astype(x.dtype))
+        with jax.named_scope("dit.adaln"):
+            xn2 = rms_norm(x, p["ln2"]) * (1 + sc2[:, None]) + sh2[:, None]
+        with jax.named_scope("dit.mlp"):
+            hmid = jnp.einsum("bsd,df->bsf", xn2,
+                              p["mlp_wi"].astype(x.dtype))
+            g, u = jnp.split(hmid, 2, axis=-1)
+            x = ctx.shard_residual(x + g2[:, None] * jnp.einsum(
+                "bsf,fd->bsd", jax.nn.silu(g) * u,
+                p["mlp_wo"].astype(x.dtype)))
         ys = (layer_plan if return_plans and plan_needed else None,
               (retention, replanned) if adaptive else None)
         return x, ys
@@ -208,8 +219,10 @@ def forward(params, cfg: ArchConfig, latents, t,
               else (params["layers"], plans))
         x, (out_plans, drift_ys) = jax.lax.scan(
             ctx.maybe_remat(body), x, xs)
-    x = rms_norm(x, params["ln_f"])
-    out = jnp.einsum("bnd,dp->bnp", x, params["patch_out"].astype(x.dtype))
+    with jax.named_scope("dit.head"):
+        x = rms_norm(x, params["ln_f"])
+        out = jnp.einsum("bnd,dp->bnp", x,
+                         params["patch_out"].astype(x.dtype))
     rets = (out,)
     if return_plans:
         rets += (out_plans,)

@@ -161,6 +161,9 @@ class ServeStats:
     prefill_tokens: int = 0
     decode_tokens: int = 0
     prefill_s: float = 0.0
+    # the LM engine's and scheduler's decode wall time; the DiT
+    # scheduler leaves it 0 (its tick is timed by the profiler spans
+    # `dit.sched.tick` and `dit.sched.readback`, serving/diffusion.py)
     decode_s: float = 0.0
     # plan-reuse accounting (layer granularity; DESIGN.md "Plan
     # lifetime & drift"): builds = first-chunk plans, replans =

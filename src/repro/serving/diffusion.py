@@ -37,6 +37,7 @@ requests populate the whole timestep axis for everyone behind them.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Deque, Iterator, List, Optional
@@ -199,7 +200,8 @@ class DiffusionScheduler:
                               cond1 if cross else None, dtype, backend,
                               return_plans=plan_needed)
             vel, plans = out if plan_needed else (out, None)
-            new = lat1 - dt1[:, None, None] * vel.astype(lat1.dtype)
+            with jax.named_scope("dit.commit"):
+                new = lat1 - dt1[:, None, None] * vel.astype(lat1.dtype)
             return new, plans
 
         def admit_cached(params, lat1, t1, dt1, cond1, plans, thr):
@@ -210,7 +212,8 @@ class DiffusionScheduler:
                 params, cfg, lat1, t1, cond1 if cross else None, dtype,
                 backend, plans=plans, return_plans=True,
                 drift_threshold=thr)
-            new = lat1 - dt1[:, None, None] * vel.astype(lat1.dtype)
+            with jax.named_scope("dit.commit"):
+                new = lat1 - dt1[:, None, None] * vel.astype(lat1.dtype)
             return new, plans, info
 
         def tick(params, latents, tv, dtv, cond, plans, thr, mask):
@@ -226,14 +229,15 @@ class DiffusionScheduler:
                 vel = dit.forward(params, cfg, latents, tv,
                                   cond if cross else None, dtype, backend)
                 new_plans, info = None, None
-            new_lat = latents - dtv[:, None, None] * vel.astype(
-                latents.dtype)
-            latents = jnp.where(mask[:, None, None], new_lat, latents)
-            if plan_needed:
-                def sel(n, o):
-                    m = mask.reshape((1, -1) + (1,) * (n.ndim - 2))
-                    return jnp.where(m, n, o)
-                plans = jax.tree_util.tree_map(sel, new_plans, plans)
+            with jax.named_scope("dit.commit"):
+                new_lat = latents - dtv[:, None, None] * vel.astype(
+                    latents.dtype)
+                latents = jnp.where(mask[:, None, None], new_lat, latents)
+                if plan_needed:
+                    def sel(n, o):
+                        m = mask.reshape((1, -1) + (1,) * (n.ndim - 2))
+                        return jnp.where(m, n, o)
+                    plans = jax.tree_util.tree_map(sel, new_plans, plans)
             return latents, plans, info
 
         self._admit_fresh_jit = jax.jit(admit_fresh)
@@ -289,6 +293,8 @@ class DiffusionScheduler:
                           - np.float32(r.steps_done) * self._dt[j])
 
     # -- admission ---------------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function,
+                       name="dit.sched.admit")
     def _admit_next(self, slot: int, events: List[StreamEvent]):
         r = self._queue.popleft()
         r.state = RequestState.PREFILLING
@@ -355,7 +361,8 @@ class DiffusionScheduler:
 
     def _finish(self, slot: int, events: List[StreamEvent]):
         r = self._slots[slot]
-        r.result = np.asarray(dit.retire_denoise_slot(self._lat, slot))
+        with jax.profiler.TraceAnnotation("dit.sched.retire"):
+            r.result = np.asarray(dit.retire_denoise_slot(self._lat, slot))
         r.state = RequestState.FINISHED
         r.metrics.finish_t = time.time()
         r.slot = None
@@ -373,9 +380,18 @@ class DiffusionScheduler:
         self.stats.plan_cache_evictions = self.cache.evictions
 
     # -- the tick ----------------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function,
+                       name="dit.sched.step")
     def step(self) -> List[StreamEvent]:
         """Admit queued requests into free slots, then run ONE batched
-        denoise step over every active slot. Returns the events."""
+        denoise step over every active slot. Returns the events.
+
+        Host spans (`jax.profiler.TraceAnnotation`, seen by any profiler
+        trace around the call): `dit.sched.step` the whole call,
+        `dit.sched.admit` each admission, `dit.sched.tick` the tick's
+        inputs and dispatch, `dit.sched.readback` the reads of the
+        tick's drift info (where the host waits for the tick),
+        `dit.sched.retire` each finished slot's read-out."""
         events: List[StreamEvent] = []
         for slot in range(self.num_slots):
             if self._slots[slot] is None and self._queue:
@@ -384,35 +400,16 @@ class DiffusionScheduler:
                   if self._slots[j] is not None]
         if not active:
             return events
-        nl, ns = self.cfg.num_layers, self.num_slots
-        tv = np.zeros((ns,), np.float32)
-        mask = np.zeros((ns,), bool)
-        thr = np.ones((nl, ns), np.float32)  # >= 1.0: inert rows
-        for j in active:
-            r = self._slots[j]
-            tv[j] = self._slot_t(j)
-            mask[j] = True
-            if self.refresh_mode == "fixed":
-                # the upcoming step index is steps_done; 0.0 forces the
-                # row's re-plan, 1.0 pins reuse — dit.sample's static
-                # schedule expressed per slot
-                thr[:, j] = (0.0 if r.steps_done % self.refresh_interval
-                             == 0 else 1.0)
-            else:
-                thr[:, j] = self._thr_layers
-        t_wall = time.time()
-        self._lat, self._plans, info = self._tick_jit(
-            self.params, self._lat, jnp.asarray(tv),
-            jnp.asarray(self._dt), self._cond, self._plans,
-            jnp.asarray(thr), jnp.asarray(mask))
-        self.stats.decode_s += time.time() - t_wall
+        info = self._dispatch_tick(active)
         if info is not None:
-            rep = np.asarray(info["replanned"])[:, active]
+            nl = self.cfg.num_layers
+            with jax.profiler.TraceAnnotation("dit.sched.readback"):
+                rep = np.asarray(info["replanned"])[:, active]
+                retention = np.asarray(info["retention"])[:, active]
             n_replan = int(rep.sum())
             self.stats.plan_replans += n_replan
             self.stats.plan_reuses += nl * len(active) - n_replan
-            self.stats.last_retention = float(
-                np.min(np.asarray(info["retention"])[:, active]))
+            self.stats.last_retention = float(np.min(retention))
         self.stats.slot_steps_active += len(active)
         self.stats.slot_steps_total += self.num_slots
         self.stats.denoise_steps += len(active)
@@ -435,6 +432,33 @@ class DiffusionScheduler:
                 self._finish(j, events)
         self._sync_cache_stats()
         return events
+
+    @functools.partial(jax.profiler.annotate_function,
+                       name="dit.sched.tick")
+    def _dispatch_tick(self, active: List[int]):
+        """Build the tick's per-slot inputs and dispatch it; returns the
+        drift info (None without a plan) without waiting for it."""
+        nl, ns = self.cfg.num_layers, self.num_slots
+        tv = np.zeros((ns,), np.float32)
+        mask = np.zeros((ns,), bool)
+        thr = np.ones((nl, ns), np.float32)  # >= 1.0: inert rows
+        for j in active:
+            r = self._slots[j]
+            tv[j] = self._slot_t(j)
+            mask[j] = True
+            if self.refresh_mode == "fixed":
+                # the upcoming step index is steps_done; 0.0 forces the
+                # row's re-plan, 1.0 pins reuse — dit.sample's static
+                # schedule expressed per slot
+                thr[:, j] = (0.0 if r.steps_done % self.refresh_interval
+                             == 0 else 1.0)
+            else:
+                thr[:, j] = self._thr_layers
+        self._lat, self._plans, info = self._tick_jit(
+            self.params, self._lat, jnp.asarray(tv),
+            jnp.asarray(self._dt), self._cond, self._plans,
+            jnp.asarray(thr), jnp.asarray(mask))
+        return info
 
     def drain(self) -> List[DenoiseRequest]:
         """Run until every submitted request has finished; returns all

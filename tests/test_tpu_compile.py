@@ -123,3 +123,55 @@ def test_fused_decode_paged_compiles(one_chip):
              ((bh, 1), I32), ((bh,), I32), *toks,
              ((HKV, pages, BKV, D), BF16), ((HKV, pages, BKV, D), BF16),
              ((HKV, pages, D, D), F32), ((HKV, pages, D), F32), *tots)
+
+
+# (kernel name, jitted entry, static arguments, operand shapes) at small
+# widths: one launch of each Pallas kernel
+_S, _N, _T = 2, 512, 8
+_ROWS = [((_S, _T, 2), I32), ((_S, _T), I32), ((_S, _N, D), BF16),
+         ((_S, _N, D), BF16), ((_S, _N, D), BF16)]
+_DEC = [((2, 1, 2), I32), ((2, 1), I32), ((2, 1), I32), ((2,), I32),
+        ((2, 1, D), F32), ((2, 1, D), F32)]
+_DEC_STATE = [((1, 1, D, D), F32), ((1, 1, D), F32), ((1, 1, D, D), F32),
+              ((1, 1, D), F32)]
+NAMED = {
+    "sla_fwd": (sla_fwd, dict(causal=False, block_q=64),
+                _ROWS + [((_S, _N, D), BF16), ((_S, _T, D, D), F32),
+                         ((_S, _T, D), F32)]),
+    "sla_bwd_dq": (sla_bwd_dq, dict(causal=False, block_q=64),
+                   _ROWS + [((_S, _N, D), F32), ((_S, _N), F32),
+                            ((_S, _N), F32)]),
+    "sla_bwd_dkv": (sla_bwd_dkv, dict(causal=False, block_q=64),
+                    _ROWS + [((_S, _N, D), F32), ((_S, _N), F32),
+                             ((_S, _N), F32)]),
+    "sla_decode": (_fused_decode, dict(group=2),
+                   _DEC + [((1, _T, 64, D), BF16), ((1, _T, 64, D), BF16),
+                           ((1, _T, D, D), F32), ((1, _T, D), F32)]
+                   + _DEC_STATE),
+    "sla_decode_paged": (_fused_decode_paged, dict(group=2, hkv=1),
+                         _DEC[:1] + _DEC + [((1, 9, 64, D), BF16),
+                                            ((1, 9, 64, D), BF16),
+                                            ((1, 9, D, D), F32),
+                                            ((1, 9, D), F32)]
+                         + _DEC_STATE),
+}
+
+
+@pytest.mark.parametrize("name", list(NAMED))
+def test_kernel_carries_its_name(one_chip, name):
+    """Each kernel's custom call is named after the kernel, and its
+    op_name ends with the kernel's name, whatever jitted function wraps
+    it: the profile's op names and named scopes find it by that name."""
+    entry, static, shapes = NAMED[name]
+    fn = functools.partial(entry, scale=D ** -0.5, block_kv=64,
+                           interpret=False, **static)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [ln for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        head = ln.strip().removeprefix("ROOT ").split(" = ", 1)[0]
+        assert head.startswith(f"%{name}."), head
+        assert f'/{name}/pallas_call"' in ln
