@@ -1,14 +1,20 @@
 """Fused SLA forward Pallas TPU kernel (paper Alg. 1, TPU adaptation).
 
-Grid: (B*H, T_m, K_sel) — the trailing axis iterates the *critical* KV
-blocks of one query row, streamed HBM->VMEM through a scalar-prefetched
-lookup table (`lut`) so only selected blocks are ever copied. Online
-softmax state (m, l, acc) lives in VMEM scratch, carried across the
-sequential trailing grid axis. At the last step the kernel finalizes the
-sparse output O^s, the log-sum-exp L (for the backward pass), and merges
-the linear branch O^l = phi(Q_i) H_i / (phi(Q_i) Z_i) from the
-pre-aggregated per-row (H_i, Z_i) — the single-pass fusion of sparse +
-linear that is the paper's kernel contribution.
+Grid: (heads in the run, T_m) — one step per query row. K and V stay in
+HBM; the step streams the row's *live* critical KV blocks (its first
+`counts` entries of the scalar-prefetched lookup table `lut`) through
+SLOTS VMEM slots with manual DMAs, two blocks ahead of the one it
+computes, so only selected blocks are ever copied and padded LUT slots
+are neither fetched nor computed. Near its end a row starts the first
+block of the grid's next row (the next head's row 0 after the last
+row), so no row waits on an exposed copy; both grid axes are therefore
+sequential. The online softmax state (m, l, acc) is carried through the
+loop and updated one block at a time in LUT order. After the loop the
+step finalizes the sparse output O^s, the log-sum-exp L (for the
+backward pass), and merges the linear branch O^l = phi(Q_i) H_i /
+(phi(Q_i) Z_i) from the pre-aggregated per-row (H_i, Z_i) — the
+single-pass fusion of sparse + linear that is the paper's kernel
+contribution.
 
 All matmuls accumulate in f32 (MXU-native); inputs may be bf16.
 """
@@ -30,6 +36,8 @@ LANES = 128
 # prefetch lives in SMEM (1 MiB on TPU v5e), shared with the row counts
 # and the kernel's own scalars; a longer LUT is split over head runs.
 SMEM_LUT_ENTRIES = 128 * 1024
+# VMEM slots for K/V blocks: the block being computed and two in flight.
+SLOTS = 3
 
 
 def head_runs(bh, group, per_head):
@@ -49,54 +57,123 @@ def _dot(a, b, trans_b=False):
 
 
 def _fwd_kernel(lut_ref, counts_ref, base_ref,  # scalar prefetch
-                q_ref, k_ref, v_ref, qp_ref, hi_ref, zi_ref,  # inputs
+                q_ref, k_hbm, v_hbm, qp_ref, hi_ref, zi_ref,  # inputs
                 os_ref, ol_ref, lse_ref,  # outputs
-                acc_ref, m_ref, l_ref,  # VMEM scratch
+                k_buf, v_buf, k_sem, v_sem, slot_ref,  # KV slots
                 *, scale: float, tm: int, k_sel: int, causal: bool,
-                block_q: int, block_kv: int):
-    bh, i, s = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+                block_q: int, block_kv: int, h0: int, group: int, hr: int):
+    bh, i = pl.program_id(0), pl.program_id(1)
+    count = counts_ref[bh, i]
+    d = q_ref.shape[-1]
+    row = (bh * tm + i) * k_sel  # the row's first LUT entry
+    kv_head = (h0 + bh) // group
 
-    @pl.when(s == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def copies(kvh, j, slot):
+        return (pltpu.make_async_copy(k_hbm.at[kvh, j], k_buf.at[slot],
+                                      k_sem.at[slot]),
+                pltpu.make_async_copy(v_hbm.at[kvh, j], v_buf.at[slot],
+                                      v_sem.at[slot]))
 
-    @pl.when(s < counts_ref[bh, i])
-    def _step():
+    def start(kvh, j, slot):
+        for c in copies(kvh, j, slot):
+            c.start()
+
+    def start_next_row(slot):
+        """Start the first block of the grid's next row (the next head's
+        row 0 after the last row), if there is one and it has any."""
+        wrap = i == tm - 1
+        nb = jnp.where(wrap, bh + 1, bh)
+        nr = jnp.where(wrap, 0, i + 1)
+
+        @pl.when(nb < hr)
+        def _():
+            @pl.when(counts_ref[nb, nr] > 0)
+            def _():
+                start((h0 + nb) // group, lut_ref[(nb * tm + nr) * k_sel],
+                      slot)
+
+    @pl.when((bh == 0) & (i == 0))
+    def _first_row():
+        slot_ref[0] = 0
+
+        @pl.when(count > 0)
+        def _():
+            start(kv_head, lut_ref[row], 0)
+
+    # Entry s of this row sits in slot (slot0 + s) % SLOTS; entry 0 was
+    # started by the previous row, entries 1 .. SLOTS-2 start here.
+    slot0 = slot_ref[0]
+    slot_of = lambda s: jax.lax.rem(slot0 + s, SLOTS)
+    for s in range(1, SLOTS - 1):
+        @pl.when(s < count)
+        def _():
+            start(kv_head, lut_ref[row + s], slot_of(s))
+
+    def block(s, state):
+        """Online-softmax update by LUT entry `s`; m and l are carried
+        lane-broadcast, (bq, LANES)."""
+        m_prev, l_prev, acc = state
+        slot = slot_of(s)
+        for c in copies(0, 0, slot):  # a wait needs only the slot
+            c.wait()
         q = q_ref[0].astype(jnp.float32)
-        kk = k_ref[0].astype(jnp.float32)
+        kk = k_buf[slot, :, :d].astype(jnp.float32)
         sij = _dot(q, kk, trans_b=True) * scale  # (bq, bkv) f32
         if causal:
-            j = lut_ref[(bh * tm + i) * k_sel + s]
+            j = lut_ref[row + s]
             rows = (base_ref[0] + i) * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 0)
             cols = j * block_kv + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 1)
             sij = jnp.where(rows >= cols, sij, NEG_INF)
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(sij, axis=-1))
-        p = jnp.exp(sij - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(sij, axis=-1, keepdims=True))
+        p = jnp.exp(sij - m_new[:, :1])
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + _dot(p, v_ref[0].astype(jnp.float32)))
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = (acc * alpha[:, :1]
+               + _dot(p, v_buf[slot, :, :d].astype(jnp.float32)))
+        return m_new, l_new, acc
 
-    @pl.when(s == k_sel - 1)
-    def _finalize():
-        m, l = m_ref[:, :1], l_ref[:, :1]  # (bq, 1) columns
-        os_ref[0] = (acc_ref[...] / l).astype(os_ref.dtype)
-        lse_ref[0] = (m + jnp.log(l)).astype(lse_ref.dtype)
-        # Linear branch (Eq. 5): one (bq,d)x(d,d) matmul + normalizer.
-        qp = qp_ref[0].astype(jnp.float32)
-        num = _dot(qp, hi_ref[0, 0])
-        den = _dot(qp, zi_ref[0, 0], trans_b=True)  # (bq,d)x(1,d)^T: (bq, 1)
-        live = den > EPS
-        ol = jnp.where(live, num / jnp.where(live, den, 1.0), 0.0)
-        ol_ref[0] = ol.astype(ol_ref.dtype)
+    def steady(s, state):
+        """Entries with SLOTS-1 more of the row behind them: start entry
+        s + SLOTS - 1 with no branch, so the copy and the block's
+        compute share one basic block."""
+        start(kv_head, lut_ref[row + s + SLOTS - 1], slot_of(s + SLOTS - 1))
+        return block(s, state)
+
+    n_steady = jnp.maximum(count - (SLOTS - 1), 0)
+
+    def last(s, state):
+        """The row's last entries: the first of them starts the next
+        row's entry 0, in the slot this row's entry `count` would take."""
+        @pl.when(s == n_steady)
+        def _():
+            start_next_row(slot_of(count))
+
+        return block(s, state)
+
+    state = (jnp.full((block_q, LANES), NEG_INF, jnp.float32),
+             jnp.zeros((block_q, LANES), jnp.float32),
+             jnp.zeros((block_q, d), jnp.float32))
+    state = jax.lax.fori_loop(0, n_steady, steady, state)
+    m, l, acc = jax.lax.fori_loop(n_steady, count, last, state)
+
+    @pl.when(count == 0)
+    def _():
+        start_next_row(slot0)
+
+    slot_ref[0] = slot_of(count)
+
+    m, l = m[:, :1], l[:, :1]  # (bq, 1) columns
+    os_ref[0] = (acc / l).astype(os_ref.dtype)
+    lse_ref[0] = (m + jnp.log(l)).astype(lse_ref.dtype)
+    # Linear branch (Eq. 5): one (bq,d)x(d,d) matmul + normalizer.
+    qp = qp_ref[0].astype(jnp.float32)
+    num = _dot(qp, hi_ref[0, 0])
+    den = _dot(qp, zi_ref[0, 0], trans_b=True)  # (bq,d)x(1,d)^T: (bq, 1)
+    live = den > EPS
+    ol = jnp.where(live, num / jnp.where(live, den, 1.0), 0.0)
+    ol_ref[0] = ol.astype(ol_ref.dtype)
 
 
 @functools.partial(
@@ -134,47 +211,46 @@ def sla_fwd(lut, counts, q, k, v, qp, hi, zi, *, scale, causal,
     tm = n // block_q
     k_sel = lut.shape[-1]
 
-    kern = functools.partial(
-        _fwd_kernel, scale=scale, tm=tm, k_sel=k_sel, causal=causal,
-        block_q=block_q, block_kv=block_kv)
     runs = head_runs(bh_q, group, tm * k_sel)
     hr = runs[0][1]
+    d_pad = -(-d // LANES) * LANES
 
     def launch(h0):
         """One pallas_call over heads [h0, h0 + hr): the index maps add
         h0, so operands are passed whole and only the LUT is sliced."""
-        def row(bh, i, s, *_):
+        def row(bh, i, *_):
             return (h0 + bh, i, 0)
 
-        def kv_map(bh, i, s, lut_ref, counts_ref, base_ref):
-            return ((h0 + bh) // group,
-                    lut_ref[(bh * tm + i) * k_sel + s], 0)
-
+        kern = functools.partial(
+            _fwd_kernel, scale=scale, tm=tm, k_sel=k_sel, causal=causal,
+            block_q=block_q, block_kv=block_kv, h0=h0, group=group, hr=hr)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(hr, tm, k_sel),
+            grid=(hr, tm),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), row),                     # q
-                pl.BlockSpec((1, block_kv, d), kv_map),                 # k
-                pl.BlockSpec((1, block_kv, d), kv_map),                 # v
+                pl.BlockSpec(memory_space=pl.ANY),                      # k
+                pl.BlockSpec(memory_space=pl.ANY),                      # v
                 pl.BlockSpec((1, block_q, d), row),                     # qp
-                pl.BlockSpec((1, 1, d, d), lambda bh, i, s, *_:
+                pl.BlockSpec((1, 1, d, d), lambda bh, i, *_:
                              (h0 + bh, i, 0, 0)),                       # hi
-                pl.BlockSpec((1, 1, 1, d), lambda bh, i, s, *_:
+                pl.BlockSpec((1, 1, 1, d), lambda bh, i, *_:
                              (h0 + bh, i, 0, 0)),                       # zi
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_:
+                pl.BlockSpec((1, block_q, d), lambda bh, i, *_:
                              (bh, i, 0)),                               # o_s
-                pl.BlockSpec((1, block_q, d), lambda bh, i, s, *_:
+                pl.BlockSpec((1, block_q, d), lambda bh, i, *_:
                              (bh, i, 0)),                               # o_l
-                pl.BlockSpec((1, block_q, 1), lambda bh, i, s, *_:
+                pl.BlockSpec((1, block_q, 1), lambda bh, i, *_:
                              (bh, i, 0)),                               # lse
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),      # acc
-                pltpu.VMEM((block_q, LANES), jnp.float32),  # m (lane-bcast)
-                pltpu.VMEM((block_q, LANES), jnp.float32),  # l (lane-bcast)
+                pltpu.VMEM((SLOTS, block_kv, d_pad), k.dtype),  # K
+                pltpu.VMEM((SLOTS, block_kv, d_pad), v.dtype),  # V
+                pltpu.SemaphoreType.DMA((SLOTS,)),              # K sems
+                pltpu.SemaphoreType.DMA((SLOTS,)),              # V sems
+                pltpu.SMEM((1,), jnp.int32),        # slot of entry 0
             ],
         )
         return pl.pallas_call(
@@ -185,14 +261,27 @@ def sla_fwd(lut, counts, q, k, v, qp, hi, zi, *, scale, causal,
                 jax.ShapeDtypeStruct((hr, n, d), jnp.float32),
                 jax.ShapeDtypeStruct((hr, n, 1), jnp.float32),
             ],
+            # Blocks are prefetched across grid steps, so the steps run
+            # in order on one core.
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
             name="sla_fwd",
         )(lut[h0:h0 + hr].reshape(-1), counts[h0:h0 + hr], base,
-          q, k, v, qp, hi, zi[:, :, None, :])
+          q, kb, vb, qp, hi, zi[:, :, None, :])
 
     with jax.named_scope("sla.sparse"):
         if base is None:
             base = jnp.zeros((1,), jnp.int32)
+        # K/V stay in HBM, viewed as (BH_kv, T_n, block_kv, D_pad) so a
+        # LUT entry names one block along a leading axis. Mosaic slices
+        # an HBM ref only along whole 128-lane tiles, so an unaligned
+        # head dim (LightningDiT's 108) is zero-padded; the kernel reads
+        # the first D lanes.
+        kb, vb = (
+            (x if d_pad == d
+             else jnp.pad(x, ((0, 0), (0, 0), (0, d_pad - d))))
+            .reshape(bh_kv, -1, block_kv, d_pad) for x in (k, v))
         outs = [launch(h0) for h0, _ in runs]
         o_s, o_l, lse = (jnp.concatenate(x) for x in zip(*outs))
         return o_s, o_l, lse[:, :, 0]
