@@ -15,14 +15,16 @@ import time
 
 import numpy as np
 
-from bench import generate, program, weights, window
+from bench import generate, program, reference, weights, window
 
 
 class DiTRunner:
     family = "dit"
+    reference_function = "euler_steps"
 
     def __init__(self, cell, seed: int):
         self.cell, self.seed = cell, seed
+        self.ref = reference.named(cell, self.reference_function)
         self.traffic = cell.traffic
         self.moved = set()     # rids the window advanced
         self.admitted = []     # (rid, slot, steps, latents after the step)
@@ -165,7 +167,6 @@ class DiTRunner:
 
         Each is the largest over the answers. No admitted request reads
         as an infinite error."""
-        from bench.reference import dit as ref
         from bench.reference.numerics import BELOW
 
         arch = self.cell.config["model"]
@@ -178,10 +179,10 @@ class DiTRunner:
         for req, n, x_prog in self.samples:
             args = (self.params, arch, req.latent, req.t_start,
                     req.num_steps, 0, n, self.conds[req.cond])
-            x_ref = np.asarray(ref.euler_steps(*args))
-            x_floor = np.asarray(ref.euler_steps(*args, inputs=stated))
+            x_ref = np.asarray(self.ref.euler_steps(*args))
+            x_floor = np.asarray(self.ref.euler_steps(*args, inputs=stated))
             if control:
-                x_prog = np.asarray(ref.euler_steps(
+                x_prog = np.asarray(self.ref.euler_steps(
                     *args, inputs=BELOW[stated]))
             upd = x_ref - req.latent
             scale = max(float(np.linalg.norm(upd)), 1e-30)

@@ -17,14 +17,16 @@ import time
 
 import numpy as np
 
-from bench import generate, program, weights, window
+from bench import generate, program, reference, weights, window
 
 
 class LMRunner:
     family = "lm"
+    reference_function = "served_logits"
 
     def __init__(self, cell, seed: int):
         self.cell, self.seed = cell, seed
+        self.ref = reference.named(cell, self.reference_function)
         self.traffic = cell.traffic
         self.reqs = {}       # rid -> record
         self.counters = {}
@@ -173,17 +175,15 @@ class LMRunner:
         the reference's best logit at that position, over the sampled
         requests. With control=True the served tokens are replaced by the
         tokens that the float8 reference puts first."""
-        from bench.reference import lm as ref
-
         arch = self.cell.config["model"]
         worst, gaps = 0.0, []
         for prompt, served in self.samples:
             toks = self._sequence(prompt, served)
-            lg = np.asarray(ref.served_logits(self.params, arch, toks,
-                                              self.bucket))
+            lg = np.asarray(self.ref.served_logits(self.params, arch, toks,
+                                                   self.bucket))
             rows = lg[:len(served)]
             if control:
-                ctl = np.asarray(ref.served_logits(
+                ctl = np.asarray(self.ref.served_logits(
                     self.params, arch, toks, self.bucket, inputs="float8"))
                 pick = np.argmax(ctl[:len(served)], axis=-1)
             else:

@@ -7,7 +7,8 @@ import copy
 from bench import spec
 
 DIT_CONFIG = {
-    "arch": "wan2_1_1_3b", "family": "dit", "weight_dtype": "bfloat16",
+    "arch": "wan2_1_1_3b", "family": "dit", "reference": "dit",
+    "weight_dtype": "bfloat16",
     "compute_dtype": "float32", "product_inputs": "bfloat16",
     "backend": "gather",
     "model": {"num_layers": 2, "d_model": 128, "num_heads": 4,
@@ -23,7 +24,8 @@ DIT_TRAFFIC = {"kind": "dit", "loop": "backlog", "seq_len": 256,
                "slots": 2, "num_steps": 6, "t_start": 1.0,
                "fill_steps": [6, 3], "backlog": 4, "cond_pool": 2}
 LM_CONFIG = {
-    "arch": "qwen3-1.7b", "family": "lm", "weight_dtype": "float32",
+    "arch": "qwen3-1.7b", "family": "lm", "reference": "lm",
+    "weight_dtype": "float32",
     "compute_dtype": "float32", "backend": "gather",
     "serving": {"slots": 3, "max_len": 256, "prefill_bucket": 128},
     "model": {"num_layers": 2, "d_model": 128, "num_heads": 4,
