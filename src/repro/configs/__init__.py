@@ -15,10 +15,12 @@ _ARCH_MODULES = {
     "internvl2-1b": "internvl2_1b",
     "wan2_1_1_3b": "wan2_1_1_3b",
     "lightningdit_1b": "lightningdit_1b",
+    "hunyuanvideo-t2": "hunyuanvideo_t2",
 }
 
 ASSIGNED_ARCHS = list(_ARCH_MODULES)[:10]  # the 10 assigned (x4 shapes)
-PAPER_ARCHS = list(_ARCH_MODULES)[10:]  # the paper's own models
+# the paper's own models, and the video DiT served beside them
+PAPER_ARCHS = list(_ARCH_MODULES)[10:]
 
 
 def get_arch(name: str) -> ArchConfig:

@@ -10,7 +10,7 @@ from repro.core.config import SLAConfig
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str  # dense | moe | ssm | hybrid | encdec | vlm | dit
+    family: str  # dense | moe | ssm | hybrid | encdec | vlm | dit | mmdit
     num_layers: int
     d_model: int
     num_heads: int
@@ -53,9 +53,32 @@ class ArchConfig:
     patch_dim: int = 0  # latent channel dim for DiT io
     cross_attn: bool = False
     cond_len: int = 0
+    # MMDiT (family "mmdit"): the first `double_layers` of the num_layers
+    # blocks are dual-stream, the rest single-stream; 3-D RoPE splits the
+    # head dim over (t, h, w) as `rope_axes`; a latent frame is
+    # `latent_hw` patches, and the frame count follows from the length
+    double_layers: int = 0
+    rope_axes: Tuple[int, ...] = ()
+    latent_hw: Tuple[int, ...] = ()
 
     sla: SLAConfig = SLAConfig()
     tie_embeddings: bool = True
+
+    def __post_init__(self):
+        # sizes read from JSON arrive as lists; keep the config hashable
+        for name in ("rope_axes", "latent_hw"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+
+    @property
+    def is_dit(self) -> bool:
+        """A denoiser served by the DiffusionScheduler (dit or mmdit)."""
+        return self.family in ("dit", "mmdit")
+
+    @property
+    def text_conditioned(self) -> bool:
+        """Takes (cond_len, d_model) text states: through cross-attention
+        (dit) or as the joint sequence's text stream (mmdit)."""
+        return self.cross_attn or self.family == "mmdit"
 
     # reduced config factory for smoke tests
     def smoke(self) -> "ArchConfig":
@@ -89,6 +112,10 @@ class ArchConfig:
             changes.update(sliding_window=64)
         if self.patch_dim:
             changes.update(patch_dim=16, cond_len=16)
+        if self.double_layers:
+            # 1 dual + 2 single blocks; RoPE axes scaled to head_dim 32
+            changes.update(num_layers=3, double_layers=1,
+                           rope_axes=(4, 14, 14), latent_hw=(4, 8))
         return dataclasses.replace(self, **changes)
 
 
@@ -111,6 +138,8 @@ SHAPES = {
 DIT_SHAPES = {
     "wan2_1_1_3b": ShapeConfig("dit_video_32k", 32768, 16, "train"),
     "lightningdit_1b": ShapeConfig("dit_image_1k", 1024, 256, "train"),
+    # 544 x 960 x 125 frames: 32 x 34 x 60 latent tokens (+256 text)
+    "hunyuanvideo-t2": ShapeConfig("dit_video_65k", 65280, 16, "train"),
 }
 # Reduced DiT train shapes. dit_video_4k: wan2_1_1_3b fine-tuning on one
 # four-chip v5e host, one sample per chip at an eighth of the video

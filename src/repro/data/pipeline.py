@@ -80,7 +80,7 @@ def latent_batch(cfg: ArchConfig, shape: ShapeConfig, dc: DataConfig,
         "noise": rng.standard_normal((b, n, p)).astype(np.float32),
         "t": rng.uniform(0.02, 0.98, size=(b,)).astype(np.float32),
     }
-    if cfg.cross_attn:
+    if cfg.text_conditioned:
         batch["cond"] = rng.standard_normal(
             (b, cfg.cond_len or 64, cfg.d_model)).astype(np.float32)
     return batch
@@ -91,7 +91,7 @@ def make_iterator(cfg: ArchConfig, shape: ShapeConfig,
                   start_step: int = 0) -> Iterator[Dict[str, np.ndarray]]:
     dc = dc or DataConfig()
     step = start_step
-    fn = latent_batch if cfg.family == "dit" else token_batch
+    fn = latent_batch if cfg.is_dit else token_batch
     while True:
         yield fn(cfg, shape, dc, step)
         step += 1

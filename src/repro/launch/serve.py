@@ -287,7 +287,7 @@ def main(argv=None):
 
     if args.workload == "dit":
         return _run_dit(args, cfg, params, rs)
-    if cfg.family == "dit":
+    if cfg.is_dit:
         ap.error(f"--arch {args.arch} is a DiT; serve it with "
                  "--workload dit")
 

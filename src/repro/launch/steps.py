@@ -45,7 +45,7 @@ def make_prefill_step(cfg: ArchConfig, backend: str = "gather") -> Callable:
     if cfg.family == "encdec":
         def prefill_step(params, batch):
             return mdl.prefill(params, cfg, batch, backend=backend)
-    elif cfg.family == "dit":
+    elif cfg.is_dit:
         def prefill_step(params, batch):
             # DiT "prefill" = one denoising forward (its inference step)
             return mdl.forward(params, cfg, batch["latents"], batch["t"],

@@ -4,16 +4,20 @@ Wan2.1-style video DiT: patchified latent tokens, AdaLN-zero timestep
 modulation, bidirectional self-attention (SLA's target workload), optional
 cross-attention to text conditioning, flow-matching training objective.
 Covers both `wan2_1_1_3b` (video, seq ~32K) and `lightningdit_1b`
-(image, seq 1024).
+(image, seq 1024). The per-layer SLA step (`SelfAttention`), the sampler
+and the serving slot helpers are shared with the MMDiT (`mmdit.py`):
+`forward_of(cfg)` names the forward that varies.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core.config import SLAConfig
 from repro.core import masks as masks_lib
 from repro.core import plan as plan_lib
 from repro.distributed import ctx
@@ -64,11 +68,141 @@ def init(rng, cfg: ArchConfig, dtype=jnp.float32) -> dict:
     }
 
 
-def _timestep_embedding(t: jax.Array, dim: int = 256) -> jax.Array:
+def timestep_embedding(t: jax.Array, dim: int = 256) -> jax.Array:
     half = dim // 2
     freq = jnp.exp(-jnp.log(10000.0) * jnp.arange(half) / half)
     ang = t.astype(jnp.float32)[:, None] * freq[None, :]
     return jnp.concatenate([jnp.cos(ang), jnp.sin(ang)], axis=-1)
+
+
+@dataclasses.dataclass(frozen=True)
+class SelfAttention:
+    """One forward's SLA self-attention settings, and the per-layer step
+    both DiT models run: plan (or reuse, or drift-refresh) the layer's
+    block structure, then `attention(...)`. `scan` runs a layer stack with
+    that step inside and `outputs` packs what forward returns, so the
+    plan, reuse and drift contract below holds for `dit.forward` and
+    `mmdit.forward` alike."""
+
+    sla_cfg: SLAConfig
+    kind: str
+    backend: str
+    plan_needed: bool
+    adaptive: bool
+    per_sample: bool
+    return_plans: bool
+    batch: int
+    head_groups: int = 1
+
+    @classmethod
+    def build(cls, cfg: ArchConfig, batch: int, *, backend: str,
+              sla_mode: Optional[str], plans, drift_threshold,
+              per_sample_refresh: bool, return_plans: bool,
+              head_groups: int = 1):
+        sla_cfg = dataclasses.replace(cfg.sla, causal=False)
+        if sla_mode is not None:
+            sla_cfg = dataclasses.replace(sla_cfg, mode=sla_mode)
+        kind = "sla" if cfg.attention_kind == "sla" else cfg.attention_kind
+        if sla_mode is not None:
+            kind = "sla"
+        # Self-attention needs a block plan only in the sparse SLA modes.
+        plan_needed = (kind == "sla"
+                       and sla_cfg.mode not in ("full", "linear_only"))
+        adaptive = (drift_threshold is not None and plans is not None
+                    and plan_needed)
+        return cls(sla_cfg, kind, backend, plan_needed, adaptive,
+                   per_sample_refresh, return_plans, batch, head_groups)
+
+    def thresholds(self, cfg: ArchConfig, drift_threshold):
+        """Per-layer drift thresholds, (L,) or (L, B) per sample."""
+        shape = ((cfg.num_layers, self.batch) if self.per_sample
+                 else (cfg.num_layers,))
+        return jnp.broadcast_to(
+            jnp.asarray(drift_threshold, jnp.float32), shape)
+
+    def __call__(self, p, q, k, v, layer_plan, thr):
+        """Self-attention of one layer (q, k, v: (B, H, N, Dh)). Returns
+        the output and the layer's scan outputs (plan, drift info)."""
+        sla_cfg = self.sla_cfg
+        if self.adaptive and self.per_sample:
+            retention = jnp.ones((self.batch,), jnp.float32)
+            replanned = jnp.zeros((self.batch,), bool)
+        else:
+            retention = jnp.float32(1.0)
+            replanned = jnp.bool_(False)
+        routing = p.get("routing") if sla_cfg.routing_mode == "learned" \
+            else None
+        if self.plan_needed and layer_plan is None:
+            layer_plan = plan_lib.plan_attention(q, k, sla_cfg,
+                                                 routing=routing)
+        elif self.adaptive:
+            refresh = (plan_lib.refresh_plan_per_sample
+                       if self.per_sample else plan_lib.refresh_plan)
+            layer_plan, retention, replanned = refresh(
+                layer_plan, q, k, sla_cfg, thr, routing=routing)
+        o = self._execute(p["sla_proj"], q, k, v,
+                          layer_plan if self.plan_needed else None, routing)
+        ys = (layer_plan if self.return_plans and self.plan_needed
+              else None,
+              (retention, replanned) if self.adaptive else None)
+        return o, ys
+
+    def _execute(self, proj, q, k, v, plan, routing):
+        """`attention(...)` under a plan made for every head. With
+        head_groups > 1 the heads run in that many groups, one after the
+        other (a loop), so that only one group's execution temporaries
+        are live at a time; heads are independent once planned, so each
+        group computes what the whole would for its heads."""
+        def run(proj, q, k, v, plan):
+            return attention({"proj": proj}, q, k, v, self.kind,
+                             self.sla_cfg, causal=False,
+                             backend=self.backend, plan=plan,
+                             routing=routing)
+
+        g = self.head_groups
+        if g == 1:
+            return run(proj, q, k, v, plan)
+
+        def split(a, axis=1):  # head axis -> a leading group axis
+            shape = a.shape[:axis] + (g, a.shape[axis] // g) \
+                + a.shape[axis + 1:]
+            return jnp.moveaxis(a.reshape(shape), axis, 0)
+
+        o = jax.lax.map(
+            lambda xs: run(*xs),
+            (split(proj, 0), split(q), split(k), split(v),
+             None if plan is None else jax.tree_util.tree_map(split, plan)))
+        o = jnp.moveaxis(o, 0, 1)
+        return o.reshape(o.shape[0], -1, *o.shape[3:])
+
+    def scan(self, body, carry, layers, plans, thresholds):
+        """Scan `body(carry, (p, layer_plan, thr))` over a layer stack;
+        `plans`/`thresholds` cover the same layers (None: plan inline)."""
+        # `plans=None` cannot ride through scan xs (no leading layer
+        # axis), so the no-plan path scans params only and the body plans
+        # inline.
+        if plans is None:
+            return jax.lax.scan(
+                ctx.maybe_remat(lambda c, p: body(c, (p, None, None))),
+                carry, layers)
+        if self.adaptive:
+            return jax.lax.scan(ctx.maybe_remat(body), carry,
+                                (layers, plans, thresholds))
+        return jax.lax.scan(
+            ctx.maybe_remat(lambda c, xs: body(c, (*xs, None))),
+            carry, (layers, plans))
+
+    def outputs(self, out, ys):
+        """forward's return value from the output and the scan outputs
+        stacked over every layer."""
+        out_plans, drift_ys = ys
+        rets = (out,)
+        if self.return_plans:
+            rets += (out_plans,)
+        if self.adaptive:
+            rets += ({"retention": drift_ys[0],
+                      "replanned": drift_ys[1]},)
+        return rets if len(rets) > 1 else out
 
 
 def forward(params, cfg: ArchConfig, latents, t,
@@ -115,40 +249,21 @@ def forward(params, cfg: ArchConfig, latents, t,
     with jax.named_scope("dit.embed"):
         x = jnp.einsum("bnp,pd->bnd", latents.astype(compute_dtype),
                        params["patch_in"].astype(compute_dtype))
-        temb = jnp.einsum("be,ed->bd", _timestep_embedding(t * 1000.0),
+        temb = jnp.einsum("be,ed->bd", timestep_embedding(t * 1000.0),
                           params["t_embed"].astype(jnp.float32))
         temb = jax.nn.silu(temb).astype(compute_dtype)
     b, n, d = x.shape
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    import dataclasses
-    sla_cfg = dataclasses.replace(cfg.sla, causal=False)
-    if sla_mode is not None:
-        sla_cfg = dataclasses.replace(sla_cfg, mode=sla_mode)
-    kind = "sla" if cfg.attention_kind == "sla" else cfg.attention_kind
-    if sla_mode is not None:
-        kind = "sla"
-    # Self-attention needs a block plan only in the sparse SLA modes.
-    plan_needed = (kind == "sla"
-                   and sla_cfg.mode not in ("full", "linear_only"))
-    adaptive = (drift_threshold is not None and plans is not None
-                and plan_needed)
-    if adaptive:
-        thr_shape = ((cfg.num_layers, latents.shape[0])
-                     if per_sample_refresh else (cfg.num_layers,))
-        thresholds = jnp.broadcast_to(
-            jnp.asarray(drift_threshold, jnp.float32), thr_shape)
+    attn = SelfAttention.build(
+        cfg, b, backend=backend, sla_mode=sla_mode, plans=plans,
+        drift_threshold=drift_threshold,
+        per_sample_refresh=per_sample_refresh, return_plans=return_plans)
+    sla_cfg = attn.sla_cfg
+    thresholds = (attn.thresholds(cfg, drift_threshold) if attn.adaptive
+                  else None)
 
     def body(x, xs):
-        if adaptive:
-            p, layer_plan, thr = xs
-        else:
-            p, layer_plan = xs
-        if adaptive and per_sample_refresh:
-            retention = jnp.ones((b,), jnp.float32)
-            replanned = jnp.zeros((b,), bool)
-        else:
-            retention = jnp.float32(1.0)
-            replanned = jnp.bool_(False)
+        p, layer_plan, thr = xs
         with jax.named_scope("dit.adaln"):
             mod = jnp.einsum("bd,de->be", temb, p["ada"].astype(temb.dtype))
             sh1, sc1, g1, sh2, sc2, g2 = jnp.split(mod, 6, axis=-1)
@@ -160,20 +275,7 @@ def forward(params, cfg: ArchConfig, latents, t,
                 .reshape(b, n, hkv, dh).transpose(0, 2, 1, 3)
             v = jnp.einsum("bsd,de->bse", xn, p["wv"].astype(x.dtype)) \
                 .reshape(b, n, hkv, dh).transpose(0, 2, 1, 3)
-        routing = p.get("routing") if sla_cfg.routing_mode == "learned" \
-            else None
-        if plan_needed and layer_plan is None:
-            layer_plan = plan_lib.plan_attention(q, k, sla_cfg,
-                                                 routing=routing)
-        elif adaptive:
-            refresh = (plan_lib.refresh_plan_per_sample
-                       if per_sample_refresh else plan_lib.refresh_plan)
-            layer_plan, retention, replanned = refresh(
-                layer_plan, q, k, sla_cfg, thr, routing=routing)
-        o = attention({"proj": p["sla_proj"]}, q, k, v, kind, sla_cfg,
-                      causal=False, backend=backend,
-                      plan=layer_plan if plan_needed else None,
-                      routing=routing)
+        o, ys = attn(p, q, k, v, layer_plan, thr)
         with jax.named_scope("dit.attn_out"):
             o = o.transpose(0, 2, 1, 3).reshape(b, n, h * dh)
             x = ctx.shard_residual(
@@ -204,31 +306,27 @@ def forward(params, cfg: ArchConfig, latents, t,
             x = ctx.shard_residual(x + g2[:, None] * jnp.einsum(
                 "bsf,fd->bsd", jax.nn.silu(g) * u,
                 p["mlp_wo"].astype(x.dtype)))
-        ys = (layer_plan if return_plans and plan_needed else None,
-              (retention, replanned) if adaptive else None)
         return x, ys
 
-    # `plans=None` cannot ride through scan xs (no leading layer axis), so
-    # the no-plan path scans params only and the body plans inline.
-    if plans is None:
-        x, (out_plans, drift_ys) = jax.lax.scan(
-            ctx.maybe_remat(lambda x, p: body(x, (p, None))),
-            x, params["layers"])
-    else:
-        xs = ((params["layers"], plans, thresholds) if adaptive
-              else (params["layers"], plans))
-        x, (out_plans, drift_ys) = jax.lax.scan(
-            ctx.maybe_remat(body), x, xs)
+    x, ys = attn.scan(body, x, params["layers"], plans, thresholds)
     with jax.named_scope("dit.head"):
         x = rms_norm(x, params["ln_f"])
         out = jnp.einsum("bnd,dp->bnp", x,
                          params["patch_out"].astype(x.dtype))
-    rets = (out,)
-    if return_plans:
-        rets += (out_plans,)
-    if adaptive:
-        rets += ({"retention": drift_ys[0], "replanned": drift_ys[1]},)
-    return rets if len(rets) > 1 else out
+    return attn.outputs(out, ys)
+
+
+def attention_len(cfg: ArchConfig, seq_len: int) -> int:
+    """Tokens self-attention runs over: the latent tokens."""
+    return seq_len
+
+
+def forward_of(cfg: ArchConfig):
+    """The forward of the DiT model `cfg` names (dit or mmdit): the one
+    thing that varies between them in sampling, serving and the losses."""
+    from repro.models import registry
+
+    return registry.get_model(cfg).forward
 
 
 def sample(params, cfg: ArchConfig, noise, *, num_steps: int = 8,
@@ -277,6 +375,7 @@ def sample(params, cfg: ArchConfig, noise, *, num_steps: int = 8,
     if mode not in ("fixed", "adaptive"):
         raise ValueError(f"unknown plan_refresh_mode {mode!r}; "
                          "expected 'fixed' or 'adaptive'")
+    fwd = forward_of(cfg)
     b = noise.shape[0]
     x = noise
     nl = cfg.num_layers
@@ -325,7 +424,7 @@ def sample(params, cfg: ArchConfig, noise, *, num_steps: int = 8,
         # carried plans — the compiled graph is horizon-independent and
         # the planning pipeline traces exactly twice (once per branch)
         # no matter how many steps or refreshes run.
-        vel, plans = forward(params, cfg, x, tvec(0), cond, compute_dtype,
+        vel, plans = fwd(params, cfg, x, tvec(0), cond, compute_dtype,
                              backend, return_plans=True)
         x = euler(x, vel)
         if num_steps > 1:
@@ -333,12 +432,12 @@ def sample(params, cfg: ArchConfig, noise, *, num_steps: int = 8,
                 x, plans = carry
 
                 def replan(_):
-                    return forward(params, cfg, x, tvec(step), cond,
+                    return fwd(params, cfg, x, tvec(step), cond,
                                    compute_dtype, backend,
                                    return_plans=True)
 
                 def reuse(_):
-                    return (forward(params, cfg, x, tvec(step), cond,
+                    return (fwd(params, cfg, x, tvec(step), cond,
                                     compute_dtype, backend, plans=plans),
                             plans)
 
@@ -362,7 +461,7 @@ def sample(params, cfg: ArchConfig, noise, *, num_steps: int = 8,
         # plan-free attention: nothing to refresh — one scanned Euler
         # body (rolled, ISSUE 6: horizon-independent compiled graph)
         def pf_body(x, step):
-            return euler(x, forward(params, cfg, x, tvec(step), cond,
+            return euler(x, fwd(params, cfg, x, tvec(step), cond,
                                     compute_dtype, backend)), None
 
         x, _ = jax.lax.scan(pf_body, x, jnp.arange(num_steps))
@@ -370,13 +469,13 @@ def sample(params, cfg: ArchConfig, noise, *, num_steps: int = 8,
             return x, static_trace([False] * (num_steps - 1))
         return x
 
-    vel, plans = forward(params, cfg, x, tvec(0), cond, compute_dtype,
+    vel, plans = fwd(params, cfg, x, tvec(0), cond, compute_dtype,
                          backend, return_plans=True)
     x = euler(x, vel)
 
     def step_body(carry, step):
         x, plans = carry
-        vel, plans, info = forward(params, cfg, x, tvec(step), cond,
+        vel, plans, info = fwd(params, cfg, x, tvec(step), cond,
                                    compute_dtype, backend, plans=plans,
                                    return_plans=True, drift_threshold=thr)
         return (euler(x, vel), plans), (info["retention"],
@@ -437,7 +536,7 @@ def loss_fn(params, cfg: ArchConfig, batch, compute_dtype=jnp.bfloat16,
     t = batch["t"]
     xt = (1.0 - t[:, None, None]) * x0 + t[:, None, None] * noise
     target = noise - x0
-    pred = forward(params, cfg, xt, t, batch.get("cond"), compute_dtype,
+    pred = forward_of(cfg)(params, cfg, xt, t, batch.get("cond"), compute_dtype,
                    backend, sla_mode)
     return mse_loss(pred, target)
 
@@ -458,8 +557,8 @@ def distill_loss_fn(params, cfg: ArchConfig, batch,
     custom_vjp treats the plan as a constant."""
     x0, noise, t = batch["latents"], batch["noise"], batch["t"]
     xt = (1.0 - t[:, None, None]) * x0 + t[:, None, None] * noise
-    teacher = forward(params, cfg, xt, t, batch.get("cond"),
+    teacher = forward_of(cfg)(params, cfg, xt, t, batch.get("cond"),
                       compute_dtype, backend, sla_mode="full")
-    student = forward(params, cfg, xt, t, batch.get("cond"),
+    student = forward_of(cfg)(params, cfg, xt, t, batch.get("cond"),
                       compute_dtype, backend)
     return mse_loss(student, jax.lax.stop_gradient(teacher))

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.models import dit, encdec, hybrid, rwkv6, transformer
+from repro.models import dit, encdec, hybrid, mmdit, rwkv6, transformer
 
 _FAMILY = {
     "dense": transformer,
@@ -25,6 +25,7 @@ _FAMILY = {
     "hybrid": hybrid,
     "encdec": encdec,
     "dit": dit,
+    "mmdit": mmdit,
 }
 
 
@@ -38,13 +39,13 @@ def _sds(shape, dtype):
 
 def train_batch_specs(cfg: ArchConfig, shape: ShapeConfig) -> Dict[str, Any]:
     b, s = shape.global_batch, shape.seq_len
-    if cfg.family == "dit":
+    if cfg.is_dit:
         return {
             "latents": _sds((b, s, cfg.patch_dim), jnp.float32),
             "noise": _sds((b, s, cfg.patch_dim), jnp.float32),
             "t": _sds((b,), jnp.float32),
             "cond": _sds((b, cfg.cond_len or 64, cfg.d_model), jnp.float32)
-            if cfg.cross_attn else None,
+            if cfg.text_conditioned else None,
         }
     if cfg.family == "encdec":
         st = max(s // 8, 8)
@@ -83,12 +84,12 @@ def prefill_specs(cfg: ArchConfig, shape: ShapeConfig):
         return {"tokens": _sds((b, s - cfg.num_patches), jnp.int32),
                 "patch_embeds": _sds((b, cfg.num_patches, cfg.d_model),
                                      jnp.float32)}
-    if cfg.family == "dit":
+    if cfg.is_dit:
         # DiT "prefill" = one full denoising forward (its inference step)
         return {"latents": _sds((b, s, cfg.patch_dim), jnp.float32),
                 "t": _sds((b,), jnp.float32),
                 "cond": _sds((b, cfg.cond_len or 64, cfg.d_model),
-                             jnp.float32) if cfg.cross_attn else None}
+                             jnp.float32) if cfg.text_conditioned else None}
     return {"tokens": _sds((b, s), jnp.int32)}
 
 

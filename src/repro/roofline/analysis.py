@@ -144,6 +144,6 @@ def active_params(cfg) -> float:
         ffn = (d * cfg.head_dim * (cfg.num_heads * 2 + cfg.num_kv_heads * 2)
                + 3 * d * cfg.d_ff) * nseg
     emb = cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2)
-    if cfg.family == "dit":
+    if cfg.is_dit:
         emb = cfg.patch_dim * d * 2
     return attn + ffn + emb

@@ -1,8 +1,9 @@
 """Streaming DiT denoise service (DESIGN.md "Streaming DiT service").
 
 The second served workload: many users submit latents to denoise, the
-`DiffusionScheduler` continuously batches them into ONE `dit.forward`
-launch per tick. Requests at *different* timesteps share the batch —
+`DiffusionScheduler` continuously batches them into ONE forward launch
+per tick, of the DiT model the configuration names (`dit` or `mmdit`,
+through `models/registry.py`). Requests at *different* timesteps share the batch —
 the timestep embedding, AdaLN modulation, and attention are all
 row-independent, so a mixed-timestep batch computes each row exactly
 what a batch-1 run at that row's t would (the bitwise
@@ -18,7 +19,7 @@ Shape of the loop (mirrors the LM Scheduler's fixed-pool design):
   final latent is read out, the slot frees for the next admission.
 
 Plan refresh inside the batched tick uses the per-sample drift path
-(`plan_lib.refresh_plan_per_sample` via `dit.forward(...,
+(`plan_lib.refresh_plan_per_sample` via the model's `forward(...,
 per_sample_refresh=True)`): each slot keeps/rebuilds its own plans on
 its own schedule — "fixed" intervals become a per-slot 0/1 threshold
 vector, "adaptive" measures real drift — so one slot's refresh never
@@ -48,7 +49,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core import plan as plan_lib
-from repro.models import dit
+from repro.models import dit, registry
 from repro.serving.api import (RequestMetrics, RequestState, ServeStats,
                                StreamEvent, normalize_drift_threshold)
 from repro.serving.plan_cache import PlanCache
@@ -116,11 +117,11 @@ class DiffusionScheduler:
                  cache_entries: int = 256):
         from repro.core import backends as backend_registry
         backend = backend_registry.resolve(backend)
-        if cfg.family != "dit":
+        if not cfg.is_dit:
             raise ValueError(
                 f"DiffusionScheduler serves the dit family only "
-                f"(got family={cfg.family!r}; the LM families go "
-                f"behind serving.Scheduler)")
+                f"(dit, mmdit; got family={cfg.family!r}; the LM families "
+                f"go behind serving.Scheduler)")
         cfg.sla.validate()
         self.cfg = cfg
         self.params = params
@@ -129,10 +130,14 @@ class DiffusionScheduler:
         self.backend = backend
         self.compute_dtype = compute_dtype
         self.sla_cfg = dataclasses.replace(cfg.sla, causal=False)
-        if seq_len % self.sla_cfg.block_q or seq_len % self.sla_cfg.block_kv:
+        model = registry.get_model(cfg)
+        self._forward = model.forward
+        attn_len = model.attention_len(cfg, self.seq_len)
+        if attn_len % self.sla_cfg.block_q or attn_len % self.sla_cfg.block_kv:
             raise ValueError(
-                f"seq_len={seq_len} must be a multiple of the SLA block "
-                f"sizes ({self.sla_cfg.block_q}, {self.sla_cfg.block_kv}) "
+                f"seq_len={seq_len} attends over {attn_len} tokens, which "
+                f"must be a multiple of the SLA block sizes "
+                f"({self.sla_cfg.block_q}, {self.sla_cfg.block_kv}) "
                 "— the plan grid is block-aligned")
         mode = (cfg.sla.plan_refresh_mode if refresh_mode is None
                 else refresh_mode)
@@ -167,10 +172,10 @@ class DiffusionScheduler:
                               jnp.float32)
         self._cond = (jnp.zeros((num_slots, cfg.cond_len, cfg.d_model),
                                 jnp.float32)
-                      if cfg.cross_attn else None)
+                      if cfg.text_conditioned else None)
         if self.plan_needed:
-            tm = seq_len // self.sla_cfg.block_q
-            tn = seq_len // self.sla_cfg.block_kv
+            tm = attn_len // self.sla_cfg.block_q
+            tn = attn_len // self.sla_cfg.block_kv
             proto = plan_lib.empty_plan(self.sla_cfg, num_slots,
                                         cfg.num_heads, tm, tn)
             self._plans = jax.tree_util.tree_map(
@@ -191,12 +196,13 @@ class DiffusionScheduler:
     # -- jitted kernels --------------------------------------------------
     def _build_jits(self):
         cfg, dtype, backend = self.cfg, self.compute_dtype, self.backend
-        plan_needed, cross = self.plan_needed, self.cfg.cross_attn
+        plan_needed, cross = self.plan_needed, self.cfg.text_conditioned
+        forward = self._forward
 
         def admit_fresh(params, lat1, t1, dt1, cond1):
             """Step 0 of the request's trajectory: plan + first Euler
             step, exactly `dit.sample`'s pre-loop head at batch 1."""
-            out = dit.forward(params, cfg, lat1, t1,
+            out = forward(params, cfg, lat1, t1,
                               cond1 if cross else None, dtype, backend,
                               return_plans=plan_needed)
             vel, plans = out if plan_needed else (out, None)
@@ -208,7 +214,7 @@ class DiffusionScheduler:
             """Step 0 against a cached plan stack: the drift machinery
             validates each layer's cached structure; `replanned` flags
             the invalidated layers (written back to the cache)."""
-            vel, plans, info = dit.forward(
+            vel, plans, info = forward(
                 params, cfg, lat1, t1, cond1 if cross else None, dtype,
                 backend, plans=plans, return_plans=True,
                 drift_threshold=thr)
@@ -221,12 +227,12 @@ class DiffusionScheduler:
             per-slot (t, dt), per-sample plan refresh, masked commit so
             retired/free rows keep their state bitwise-untouched."""
             if plan_needed:
-                vel, new_plans, info = dit.forward(
+                vel, new_plans, info = forward(
                     params, cfg, latents, tv, cond if cross else None,
                     dtype, backend, plans=plans, return_plans=True,
                     drift_threshold=thr, per_sample_refresh=True)
             else:
-                vel = dit.forward(params, cfg, latents, tv,
+                vel = forward(params, cfg, latents, tv,
                                   cond if cross else None, dtype, backend)
                 new_plans, info = None, None
             with jax.named_scope("dit.commit"):
@@ -255,7 +261,7 @@ class DiffusionScheduler:
                 f"latent shape {latent.shape} != scheduler's "
                 f"({self.seq_len}, {self.cfg.patch_dim})")
         if cond is not None:
-            if not self.cfg.cross_attn:
+            if not self.cfg.text_conditioned:
                 raise ValueError(
                     f"{self.cfg.name} has no cross-attention; cond must "
                     "be None")
@@ -310,7 +316,7 @@ class DiffusionScheduler:
             (r.cond if r.cond is not None
              else np.zeros((self.cfg.cond_len, self.cfg.d_model),
                            np.float32))[None])
-            if self.cfg.cross_attn else None)
+            if self.cfg.text_conditioned else None)
         nl = self.cfg.num_layers
         cached = bucket = None
         if self.cache is not None:

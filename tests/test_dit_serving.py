@@ -12,8 +12,12 @@ Tiers:
     fast tier; reference + fixed-mode variants in the slow tier);
   * plan-cache drift parity: cached-plan outputs equal fresh-plan
     outputs within the conformance-matrix f32 tolerances;
-  * registry smoke: wan2_1_1_3b + lightningdit_1b build, run one
-    dit.sample step under SLA, and round-trip through the scheduler.
+  * registry smoke: wan2_1_1_3b, lightningdit_1b and hunyuanvideo-t2
+    (the MMDiT) build, run one dit.sample step under SLA, and round-trip
+    through the scheduler.
+
+The parity and smoke tests run over both DiT models (dit, mmdit): the
+scheduler and the sampler are shared, only the forward differs.
 """
 import dataclasses
 
@@ -24,7 +28,7 @@ import pytest
 
 from repro.configs import get_arch
 from repro.core import plan as plan_lib
-from repro.models import dit
+from repro.models import dit, registry
 from repro.serving.api import RequestState, stats_json_payload
 from repro.serving.diffusion import (DenoiseParams, DenoiseRequest,
                                      DiffusionScheduler)
@@ -38,6 +42,15 @@ SEQ = 32
 def lightning():
     cfg = get_arch("lightningdit_1b").smoke()
     return cfg, dit.init(jax.random.PRNGKey(0), cfg)
+
+
+@pytest.fixture(scope="module",
+                params=["lightningdit_1b", "hunyuanvideo-t2"])
+def dit_model(request):
+    """Each DiT model the scheduler serves: dit, and the mmdit (at SEQ
+    latent tokens, one 4 x 8 frame, joined by its 16 text tokens)."""
+    cfg = get_arch(request.param).smoke()
+    return cfg, registry.get_model(cfg).init(jax.random.PRNGKey(0), cfg)
 
 
 def _latent(cfg, i):
@@ -248,22 +261,22 @@ def _parity_run(cfg, params, backend, mode, **kw):
     return sched
 
 
-def test_batched_vs_sequential_bitwise_gather_adaptive(lightning):
-    cfg, params = lightning
+def test_batched_vs_sequential_bitwise_gather_adaptive(dit_model):
+    cfg, params = dit_model
     sched = _parity_run(cfg, params, "gather", "adaptive")
     assert sched.stats.admissions == len(MIXED_TRACE)
     assert sched.stats.denoise_steps == sum(s for s, _ in MIXED_TRACE)
 
 
 @pytest.mark.slow
-def test_batched_vs_sequential_bitwise_gather_fixed(lightning):
-    cfg, params = lightning
+def test_batched_vs_sequential_bitwise_gather_fixed(dit_model):
+    cfg, params = dit_model
     _parity_run(cfg, params, "gather", "fixed", refresh_interval=2)
 
 
 @pytest.mark.slow
-def test_batched_vs_sequential_bitwise_reference_adaptive(lightning):
-    cfg, params = lightning
+def test_batched_vs_sequential_bitwise_reference_adaptive(dit_model):
+    cfg, params = dit_model
     _parity_run(cfg, params, "reference", "adaptive")
 
 
@@ -316,15 +329,16 @@ def test_plan_cache_drift_parity_and_counters(lightning):
 # ---------------------------------------------------------------------------
 # registry smoke: both paper DiT configs through sample + scheduler
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("arch", ["wan2_1_1_3b", "lightningdit_1b"])
+@pytest.mark.parametrize("arch", ["wan2_1_1_3b", "lightningdit_1b",
+                                  "hunyuanvideo-t2"])
 def test_registry_dit_smoke_roundtrip(arch):
     cfg = get_arch(arch).smoke()
-    assert cfg.family == "dit"
-    params = dit.init(jax.random.PRNGKey(0), cfg)
+    assert cfg.is_dit
+    params = registry.get_model(cfg).init(jax.random.PRNGKey(0), cfg)
     lat = _latent(cfg, 0)
     cond = (np.asarray(jax.random.normal(
         jax.random.PRNGKey(9), (cfg.cond_len, cfg.d_model), jnp.float32))
-        if cfg.cross_attn else None)
+        if cfg.text_conditioned else None)
     # one dit.sample step under SLA
     one = dit.sample(params, cfg, jnp.asarray(lat[None]), num_steps=1,
                      cond=(jnp.asarray(cond[None])

@@ -49,10 +49,13 @@ def _compile(fn, sharding, *shapes):
 
 
 # (B*H, B*H_kv, N, D, causal, K_sel): Wan2.1 video (12 x 128, 32k tokens,
-# bidirectional), Qwen3-1.7B prefill (GQA 16/8 x 128, causal, 32k) and
-# LightningDiT (16 x 108 — not lane-aligned — at 1024 tokens)
+# bidirectional), Qwen3-1.7B prefill (GQA 16/8 x 128, causal, 32k),
+# LightningDiT (16 x 108 — not lane-aligned — at 1024 tokens) and
+# HunyuanVideo's joint attention (one group of 8 of its 24 heads at
+# 65,536 tokens: 2 heads per launch fill SMEM with 104,448 LUT entries)
 FWD = {
     "wan2_1_1_3b": (12, 12, 32768, 128, False, 26),
+    "hunyuanvideo-t2": (8, 8, 65536, 128, False, 51),
     "qwen3-1.7b": (16, 8, 32768, 128, True, 26),
     "lightningdit_1b": (16, 16, 1024, 108, False, 2),
 }
